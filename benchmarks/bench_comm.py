@@ -22,21 +22,22 @@ Headline (checked-in JSON, ring topology): int8+EF cuts bytes/round
 charged, so 8-bit payloads bound the ratio just under 4) and int4+EF
 7.9×, both at a final gap within 10% of the uncompressed run.
 
-The `lm_bf16_drift` section runs examples/train_lm_dagm.py twice
-(f32 vs bf16 gossip) in subprocesses at the smoke size and records the
-loss-curve delta — the measurement half of the ROADMAP bf16-drift item.
+The `lm_bf16_drift` section runs examples/train_lm_dagm.py's `main`
+twice (f32 vs bf16 gossip) in this process at the smoke size and
+records the loss-curve delta — the measurement half of the ROADMAP
+bf16-drift item.  Everything runs in one process: a child could not
+reach a chip this process already holds.
 
-Budgets: "smoke" (scripts/ci.sh tier 2: tiny dims, no LM subprocess,
+Budgets: "smoke" (scripts/ci.sh tier 2: tiny dims, no sharded/LM rows,
 no JSON rewrite), "small" (checked-in results), "full" (adds star/
 larger-d2 rows).  JSON: benchmarks/results/bench_comm.json.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
-import subprocess
 import sys
-import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -128,79 +129,64 @@ def _sweep(prob, net, specs, K, M, U, curvature, tag) -> list[Row]:
     return rows
 
 
-SHARDED_EF_SCRIPT = r"""
-import os, json, sys
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-sys.path.insert(0, sys.argv[1])
-import jax, jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh
-from repro.core import quadratic_bilevel
-from repro.solve import sharded_spec
-from repro.distributed.dagm_sharded import (
-                                            make_sharded_dagm,
-                                            open_sharded_channels,
-                                            sharded_comm_ledger)
-
-n, d1, d2, rounds = 8, 8, 128, int(sys.argv[2])
-mesh = Mesh(np.array(jax.devices()).reshape(n), ("data",))
-prob = quadratic_bilevel(n, d1, d2, seed=0)
-curv = float(max(np.linalg.eigvalsh(np.asarray(prob.data["A"][i])).max()
-                 for i in range(n)))
-x0 = jnp.broadcast_to(
-    2.0 * jax.random.normal(jax.random.PRNGKey(7), (d1,)),
-    (n, d1)).astype(jnp.float32)
-y0 = 0.01 * jax.random.normal(jax.random.PRNGKey(0), (n, d2))
-
-out = {}
-for label, spec, persist in (("identity", "identity", False),
-                             ("reset", "top_k:0.1+ef", False),
-                             ("persist", "top_k:0.1+ef", True)):
-    cfg = sharded_spec(alpha=0.05, beta=0.1, M=5, U=3,
-                       curvature=curv, comm=spec,
-                       persist_ef=persist)
-    step, _ = make_sharded_dagm(lambda x, y, b: prob.g(x, y, b),
-                                lambda x, y, b: prob.f(x, y, b),
-                                cfg, mesh)
-    x, y = x0, y0
-    if persist:
-        cs = open_sharded_channels(cfg, x, y, seed=0)
-        for r in range(rounds):
-            x, y, m, cs = step(x, y, prob.data, cs)
-    else:
-        for r in range(rounds):
-            x, y, m = step(x, y, prob.data)
-    led = sharded_comm_ledger(cfg, x[0], y[0], rounds=1)
-    out[label] = {
-        "final_gap": float(jnp.sum(
-            prob.hypergrad(jnp.mean(x, 0)) ** 2)),
-        "bytes_per_round": led.total_bytes,
-    }
-print("RESULT " + json.dumps(out))
-"""
-
-
 def _sharded_ef_rows(rounds: int = 200) -> list[Row]:
     """Persistent vs per-round-reset EF replicas on the *sharded* tier
     (ROADMAP "EF state across outer rounds" item): the reference tier
     warm-starts its inner_y/outer_x replicas across the whole K-round
     scan, while the historical sharded step reopened its channels each
-    round; `persist_ef` threads them as an extra carry.  Needs >1
-    device, hence the forced-host-platform subprocess (same pattern as
-    tests/test_sharded.py)."""
-    src = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
-    proc = subprocess.run(
-        [sys.executable, "-c", SHARDED_EF_SCRIPT, src, str(rounds)],
-        capture_output=True, text=True, timeout=1800)
-    if proc.returncode != 0:
-        return [Row("comm/sharded_ef/ERROR", 0.0,
-                    {"stderr": proc.stderr[-200:]})]
-    out = json.loads(proc.stdout.split("RESULT ", 1)[1])
+    round; `persist_ef` threads them as an extra carry.  One agent per
+    device of this process, up to 8 (benchmarks.run gives the CPU eight
+    host devices)."""
+    from jax.sharding import Mesh
+    from repro.distributed.dagm_sharded import (make_sharded_dagm,
+                                                open_sharded_channels,
+                                                sharded_comm_ledger)
+    from repro.solve import sharded_spec
+    devices = jax.devices()
+    n = min(8, len(devices))
+    if n < 2:
+        raise RuntimeError(
+            f"comm/sharded_ef needs at least 2 devices for a ring, found "
+            f"{len(devices)} {devices[0].platform} device(s)")
+    d1, d2 = 8, 128
+    mesh = Mesh(np.array(devices[:n]), ("data",))
+    prob = quadratic_bilevel(n, d1, d2, seed=0)
+    curv = float(max(np.linalg.eigvalsh(np.asarray(prob.data["A"][i])).max()
+                     for i in range(n)))
+    x0 = jnp.broadcast_to(
+        2.0 * jax.random.normal(jax.random.PRNGKey(7), (d1,)),
+        (n, d1)).astype(jnp.float32)
+    y0 = 0.01 * jax.random.normal(jax.random.PRNGKey(0), (n, d2))
+
+    out = {}
+    for label, spec, persist in (("identity", "identity", False),
+                                 ("reset", "top_k:0.1+ef", False),
+                                 ("persist", "top_k:0.1+ef", True)):
+        cfg = sharded_spec(alpha=0.05, beta=0.1, M=5, U=3,
+                           curvature=curv, comm=spec,
+                           persist_ef=persist)
+        step, _ = make_sharded_dagm(lambda x, y, b: prob.g(x, y, b),
+                                    lambda x, y, b: prob.f(x, y, b),
+                                    cfg, mesh)
+        x, y = x0, y0
+        if persist:
+            cs = open_sharded_channels(cfg, x, y, seed=0)
+            for _ in range(rounds):
+                x, y, _m, cs = step(x, y, prob.data, cs)
+        else:
+            for _ in range(rounds):
+                x, y, _m = step(x, y, prob.data)
+        led = sharded_comm_ledger(cfg, x[0], y[0], rounds=1)
+        out[label] = {
+            "final_gap": float(jnp.sum(
+                prob.hypergrad(jnp.mean(x, 0)) ** 2)),
+            "bytes_per_round": led.total_bytes,
+        }
     gid = out["identity"]["final_gap"]
     g_reset, g_persist = out["reset"]["final_gap"], \
         out["persist"]["final_gap"]
     return [Row("comm/sharded_ef/top_k:0.1+ef", 0.0, {
+        "agents": n,
         "rounds": rounds,
         "final_gap_identity": f"{gid:.3e}",
         "final_gap_reset": f"{g_reset:.3e}",
@@ -215,26 +201,16 @@ def _sharded_ef_rows(rounds: int = 200) -> list[Row]:
 
 
 def _lm_drift_rows(rounds: int = 10) -> list[Row]:
-    """f32 vs bf16 gossip on the LM smoke run (ROADMAP bf16 item)."""
-    script = os.path.join(os.path.dirname(__file__), "..", "examples",
-                          "train_lm_dagm.py")
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = os.path.abspath(src) + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    out = {}
-    with tempfile.TemporaryDirectory() as td:
-        for dtype in ("f32", "bf16"):
-            path = os.path.join(td, f"lm_{dtype}.json")
-            proc = subprocess.run(
-                [sys.executable, script, "--rounds", str(rounds),
-                 "--mixing-dtype", dtype, "--json-out", path],
-                capture_output=True, text=True, env=env, timeout=1200)
-            if proc.returncode != 0:
-                return [Row("comm/lm_bf16_drift/ERROR", 0.0,
-                            {"stderr": proc.stderr[-200:]})]
-            with open(path) as f:
-                out[dtype] = json.load(f)
+    """f32 vs bf16 gossip on the LM smoke run (ROADMAP bf16 item),
+    through examples/train_lm_dagm.py's `main` in this process."""
+    path = os.path.join(os.path.dirname(__file__), "..", "examples",
+                        "train_lm_dagm.py")
+    spec = importlib.util.spec_from_file_location("train_lm_dagm", path)
+    lm = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lm)
+    out = {dtype: lm.main(["--rounds", str(rounds),
+                           "--mixing-dtype", dtype])
+           for dtype in ("f32", "bf16")}
     f32 = np.asarray(out["f32"]["outer_loss"])
     b16 = np.asarray(out["bf16"]["outer_loss"])
     return [Row("comm/lm_bf16_drift", 0.0, {
@@ -296,16 +272,10 @@ def run(budget: str = "small") -> list[Row]:
                        K=300, M=10, U=3, curvature=curvature,
                        tag="star_n16_d256")
 
+    # a failing row raises before the checked-in JSON is rewritten
+    # (benchmarks.run turns the raise into a module ERROR + exit 1)
     rows += _sharded_ef_rows(rounds=200)
     rows += _lm_drift_rows(rounds=10)
-
-    # a failed subprocess row must not silently clobber the checked-in
-    # JSON (benchmarks.run turns the raise into a module ERROR + exit 1)
-    errors = [r for r in rows if r.name.endswith("/ERROR")]
-    if errors:
-        raise RuntimeError(
-            f"subprocess rows failed, keeping existing {RESULTS}: "
-            + "; ".join(f"{r.name}: {r.derived}" for r in errors))
 
     os.makedirs(os.path.dirname(RESULTS), exist_ok=True)
     with open(RESULTS, "w") as f:

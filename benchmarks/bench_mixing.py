@@ -4,8 +4,9 @@ Compares, per (n agents, d features, k-hop circulant topology):
 
   * dense    — `mix_apply` as W @ Y (O(n²·d) matmul, the old default),
   * circulant — MixingOp's O(n·k·d) weighted-cyclic-shift XLA path,
-  * pallas   — the banded-circulant Pallas kernel (interpret mode off
-               TPU, so its wall-clock here validates, not measures),
+  * pallas   — the banded-circulant Pallas kernel (interpreted off a
+               TPU — those rows are suffixed `_interpret` and their
+               wall-clock validates, not measures),
 
 and, per irregular (Erdős–Rényi) topology:
 
@@ -63,6 +64,16 @@ RESULTS = os.path.join(os.path.dirname(__file__), "results",
                        "bench_mixing.json")
 
 
+def _pallas_row(name: str, us: float, derived: dict) -> Row:
+    """A Pallas timing row: named and noted `_interpret` when the
+    platform interprets the kernel (see kernels.ops.pallas_interpret),
+    whose wall-clock then validates and does not measure."""
+    if kops.pallas_interpret():
+        return Row(name + "_interpret", us,
+                   {**derived, "note": "interpret-mode validation timing"})
+    return Row(name, us, derived)
+
+
 def _paired_best(base_fn, fn, y, iters: int,
                  repeats: int = 9) -> tuple[float, float]:
     """(best µs of base_fn, best µs of fn) over short *interleaved*
@@ -111,14 +122,12 @@ def _bench_case(n: int, d: int, hops: int, iters: int,
         def pk(z):
             return circulant_mix_matvec(z, w_self=s.w_self,
                                         offsets=s.offsets,
-                                        weights=s.weights, laplacian=True,
-                                        interpret=True)
+                                        weights=s.weights, laplacian=True)
         _, us_pk = timed(pk, y, iters=max(1, iters // 10), warmup=1)
 
-        rows.append(Row(f"{tag}/pallas_interpret", us_pk,
-                        {"flops": fl["flops_sparse"],
-                         "work_ratio": round(fl["work_ratio"], 2),
-                         "note": "interpret-mode validation timing"}))
+        rows.append(_pallas_row(f"{tag}/pallas", us_pk,
+                                {"flops": fl["flops_sparse"],
+                                 "work_ratio": round(fl["work_ratio"], 2)}))
     return rows
 
 
@@ -160,13 +169,12 @@ def _bench_er_case(n: int, d: int, r: float, iters: int,
         wts = jnp.asarray(sp.weights)
 
         def pk(z):
-            return sparse_mix_matvec(z, wself, idx, wts, laplacian=True,
-                                     interpret=True)
+            return sparse_mix_matvec(z, wself, idx, wts, laplacian=True)
         _, us_pk = timed(pk, y, iters=max(1, iters // 20), warmup=1)
-        rows.append(Row(f"{tag}/sparse_pallas_interpret", us_pk,
-                        {"flops": 2.0 * (n * sp.k + n) * d,
-                         "work_ratio": round(n * n / (n * sp.k + n), 2),
-                         "note": "interpret-mode validation timing"}))
+        rows.append(_pallas_row(
+            f"{tag}/sparse_pallas", us_pk,
+            {"flops": 2.0 * (n * sp.k + n) * d,
+             "work_ratio": round(n * n / (n * sp.k + n), 2)}))
     return rows
 
 
@@ -233,8 +241,9 @@ def _bench_fused_comm(n: int, d: int, iters: int) -> list[Row]:
             unfused(y + 1.0).block_until_ready()
         common = {"modeled_unfused_bytes": model["unfused_bytes"],
                   "modeled_fused_bytes": model["fused_bytes"],
-                  "traffic_reduction": model["traffic_reduction"],
-                  "note": "interpret-mode validation timing"}
+                  "traffic_reduction": model["traffic_reduction"]}
+        if kops.pallas_interpret():
+            common["note"] = "interpret-mode validation timing"
         rows.append(Row(f"{tag}/unfused", us_un,
                         {**common, "retraces": c_un.retraces}))
         rows.append(Row(f"{tag}/fused", us_fu,
@@ -252,23 +261,21 @@ def _bench_halo(n: int, d: int, iters: int) -> list[Row]:
     y = jax.random.normal(jax.random.PRNGKey(n + d), (n, d), jnp.float32)
     over = stripe_vmem_bytes(n) > VMEM_BUDGET_BYTES
     bn = pick_halo_bn(n, h_lo=2, h_hi=2) or min(n, 256)
-    interp = kops.pallas_interpret()
     tag = f"mixing/halo_n{n}_d{d}"
     xla_op = make_mixing_op(net, backend="circulant")
     plain, c_pl = _counting_jit(
         lambda z: circulant_mix_matvec_halo(
             z, w_self=s.w_self, offsets=s.offsets, weights=s.weights,
-            laplacian=True, bn=bn, interpret=interp),
+            laplacian=True, bn=bn),
         "halo_plain")
     us_xla, us_halo = _paired_best(jax.jit(xla_op.laplacian), plain, y,
                                    iters)
     plain(y + 1.0).block_until_ready()
     rows = [Row(f"{tag}/circulant_xla", us_xla,
                 {"full_stripe_exceeds_vmem": over}),
-            Row(f"{tag}/halo_interpret", us_halo,
-                {"bn": bn, "full_stripe_exceeds_vmem": over,
-                 "retraces": c_pl.retraces,
-                 "note": "interpret-mode validation timing"})]
+            _pallas_row(f"{tag}/halo", us_halo,
+                        {"bn": bn, "full_stripe_exceeds_vmem": over,
+                         "retraces": c_pl.retraces})]
 
     model = mixing_traffic_model(n, d, ef=False)
     from repro.comm import row_quant_params
@@ -277,16 +284,16 @@ def _bench_halo(n: int, d: int, iters: int) -> list[Row]:
     fused, c_fu = _counting_jit(
         lambda z, zp_, sc_, sd: circulant_mix_matvec_halo(
             z, zp_, sc_, sd, w_self=s.w_self, offsets=s.offsets,
-            weights=s.weights, bn=bn, interpret=interp, comm="int8"),
+            weights=s.weights, bn=bn, comm="int8"),
         "halo_fused_int8")
     _, us_fu = timed(lambda z: fused(z, zp, sc, seed), y,
                      iters=max(1, iters // 10), warmup=1)
     fused(y + 1.0, zp, sc, seed + 1).block_until_ready()
-    rows.append(Row(f"{tag}/halo_fused_int8_interpret", us_fu,
-                    {"bn": bn, "retraces": c_fu.retraces,
-                     "modeled_fused_bytes": model["fused_bytes"],
-                     "traffic_reduction": model["traffic_reduction"],
-                     "note": "interpret-mode validation timing"}))
+    rows.append(_pallas_row(f"{tag}/halo_fused_int8", us_fu,
+                            {"bn": bn, "retraces": c_fu.retraces,
+                             "modeled_fused_bytes": model["fused_bytes"],
+                             "traffic_reduction":
+                                 model["traffic_reduction"]}))
     return rows
 
 

@@ -42,7 +42,7 @@ import json
 import os
 import sys
 
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
+from repro.launch.mesh import roofline_terms
 
 
 def _terms(r: dict) -> dict:
@@ -50,13 +50,7 @@ def _terms(r: dict) -> dict:
     byts = r.get("bytes_corrected") or r.get("hbm_bytes_accessed", 0.0)
     coll = r.get("collective_bytes_corrected") or \
         sum(r.get("collective_bytes", {}).values())
-    t = {
-        "compute_s": flops / HW["peak_flops"],
-        "memory_s": byts / HW["hbm_bw"],
-        "collective_s": coll / HW["ici_bw"],
-    }
-    t["bottleneck"] = max(t, key=t.get)
-    return t
+    return roofline_terms(flops, byts, coll)
 
 
 def _ms(x: float) -> str:

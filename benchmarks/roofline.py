@@ -13,10 +13,10 @@
    path vs the fused Pallas kernels, and the benchmark times both paths
    at representative shapes.  The model is what the ISSUE's ≥ 2.5×
    HBM-traffic-reduction acceptance reads; the measured wall-clock
-   validates in interpret mode on CPU and *measures* on a real TPU —
-   rerun with ``REPRO_PALLAS_INTERPRET=0`` (no code change) to get
-   compiled-kernel numbers, since the fused tier picks its interpret
-   flag up from `repro.kernels.ops.pallas_interpret()`.
+   validates in interpret mode on CPU and runs the compiled kernels on
+   a TPU: the fused tier takes its interpret flag from
+   `repro.kernels.ops.pallas_interpret()`, which the platform decides.
+   The modeled HBM times use the v5e peak from `repro.launch.mesh`.
 
 Traversal accounting (one traversal = n·d·itemsize bytes through HBM):
 
@@ -37,11 +37,14 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND, chip_peaks,
+                               roofline_terms)
+
 from .common import Row, timed
 
 SMOKE_AWARE = True   # genuine cheap smoke tier (benchmarks.run contract)
 
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
+HBM_BW = chip_peaks(PRODUCTION_DEVICE_KIND)["hbm_bw"]
 DEFAULT_PATH = os.path.join(os.path.dirname(__file__), "..",
                             "dryrun_singlepod.json")
 
@@ -64,8 +67,8 @@ def mixing_traffic_model(n: int, d: int, *, ef: bool = False,
         "unfused_bytes": unfused,
         "fused_bytes": fused,
         "traffic_reduction": round(unfused / fused, 2),
-        "unfused_hbm_s": unfused / HW["hbm_bw"],
-        "fused_hbm_s": fused / HW["hbm_bw"],
+        "unfused_hbm_s": unfused / HBM_BW,
+        "fused_hbm_s": fused / HBM_BW,
     }
 
 
@@ -80,12 +83,8 @@ def rows_from_record(r: dict) -> Row | None:
     byts = r.get("bytes_corrected") or r.get("hbm_bytes_accessed", 0.0)
     coll = r.get("collective_bytes_corrected") or \
         sum(r.get("collective_bytes", {}).values())
-    terms = {
-        "compute_s": flops / HW["peak_flops"],
-        "memory_s": byts / HW["hbm_bw"],
-        "collective_s": coll / HW["ici_bw"],
-    }
-    bottleneck = max(terms, key=terms.get)
+    terms = roofline_terms(flops, byts, coll)
+    bottleneck = terms.pop("bottleneck")
     return Row(f"roofline/{r['arch']}/{r['shape']}", 0.0, {
         **{k: f"{v*1e3:.2f}ms" for k, v in terms.items()},
         "bottleneck": bottleneck,
@@ -122,7 +121,7 @@ def _mixing_kernel_rows(budget: str) -> list[Row]:
             st0 = channel_init(xla_op.comm, "x", y,
                                jax.random.PRNGKey(0))
             unfused = jax.jit(lambda z, op=xla_op: op.mix_c(z, st0)[0])
-            with kops.pallas_mode(True, interpret=interp):
+            with kops.pallas_mode(True):
                 fop = make_mixing_op(net, comm=spec)
                 assert fop._fused_plan(y) is not None
                 fused = jax.jit(lambda z, op=fop: op.mix_c(z, st0)[0])

@@ -4,12 +4,18 @@
                                             [--only fig2,fig4,...]
 
 Prints ``name,us_per_call,derived`` CSV per row (the harness contract).
+Every module runs in this one process, which holds the device: no
+module may spawn a JAX child.  On the CPU the backend gets eight host
+devices, so the sharded-tier rows have a ring to run on.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
+
+from repro.compile_cache import enable_compile_cache
 
 from . import (bench_comm, bench_faults, bench_mixing, bench_serve,
                fig2_synthetic, fig3_real, fig4_hyperrep, fig5_fairloss,
@@ -47,6 +53,14 @@ def main(argv=None) -> int:
                     help="comma-separated module names")
     args = ap.parse_args(argv)
     names = (args.only.split(",") if args.only else list(MODULES))
+    if "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        # read when the backend starts (the first jax computation; no
+        # module computes at import), so this still takes effect here
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=8").strip()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

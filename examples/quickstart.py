@@ -14,9 +14,12 @@ import dataclasses
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import make_network, quadratic_bilevel
 from repro.optim import inverse_sqrt_schedule
 from repro.solve import ScheduleSpec, dagm_spec, solve
+
+enable_compile_cache()
 
 # 1. the decentralized network (Metropolis weights, Assumption A checked)
 net = make_network("erdos_renyi", n=16, r=0.5, seed=0)

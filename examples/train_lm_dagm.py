@@ -17,30 +17,30 @@ params, a few dozen rounds); scale flags up on real hardware (the same
 script drives a pod via the production mesh).
 
     PYTHONPATH=src python examples/train_lm_dagm.py [--rounds 30]
+
+`main(argv)` returns the loss history and ledger summary, so a caller
+that already holds the devices (benchmarks/bench_comm.py) runs it in
+its own process.
 """
+import argparse
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
 
-import argparse  # noqa: E402
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
 
-import numpy as np  # noqa: E402
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
-
-from repro.configs import get_config  # noqa: E402
-from repro.data import TokenDataConfig, make_token_batch  # noqa: E402
-from repro.data.synthetic import agent_domain_bias  # noqa: E402
-from repro.comm import parse_comm_spec  # noqa: E402
-from repro.distributed.dagm_sharded import (  # noqa: E402
-    make_sharded_dagm)
-from repro.solve import sharded_spec  # noqa: E402
-from repro.models import build_model  # noqa: E402
-from repro.models.model_zoo import cross_entropy  # noqa: E402
+from repro.configs import get_config
+from repro.data import TokenDataConfig, make_token_batch
+from repro.data.synthetic import agent_domain_bias
+from repro.comm import parse_comm_spec
+from repro.distributed.dagm_sharded import (make_sharded_dagm,
+                                            sharded_comm_ledger)
+from repro.solve import sharded_spec
+from repro.models import build_model
 
 
-def main():
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--rounds", type=int, default=30)
@@ -60,7 +60,7 @@ def main():
     ap.add_argument("--json-out", default=None,
                     help="write the loss history + comm ledger summary "
                          "as JSON (benchmarks/bench_comm drift study)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     n = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()).reshape(n), ("data",))
@@ -143,21 +143,27 @@ def main():
           f"{np.round(np.exp(xbar[:D]) / np.exp(xbar[:D]).sum(), 3)}")
     print(f"[dagm-lm] outer loss {hist[0]:.4f} -> {hist[-1]:.4f} "
           f"(improved={hist[-1] < hist[0]})")
-    assert np.isfinite(hist[-1])
+    if not np.isfinite(hist[-1]):
+        raise FloatingPointError(f"outer loss diverged: {hist[-1]}")
+    local = jax.tree.map(lambda a: a[0], y)
+    led = sharded_comm_ledger(dcfg, x[0], local, rounds=args.rounds)
+    out = {"arch": cfg.name, "rounds": args.rounds, "comm": pol.spec,
+           "mixing_dtype": args.mixing_dtype, "outer_loss": hist,
+           "ledger": led.summary(args.rounds)}
     if args.json_out:
         import json
-        from repro.distributed.dagm_sharded import sharded_comm_ledger
-        local = jax.tree.map(lambda a: a[0], y)
-        led = sharded_comm_ledger(dcfg, x[0], local, rounds=args.rounds)
         with open(args.json_out, "w") as f:
-            json.dump({"arch": cfg.name, "rounds": args.rounds,
-                       "comm": pol.spec,
-                       "mixing_dtype": args.mixing_dtype,
-                       "outer_loss": hist,
-                       "ledger": led.summary(args.rounds)}, f, indent=1)
+            json.dump(out, f, indent=1)
         print(f"[dagm-lm] wrote {args.json_out}")
     print("OK")
+    return out
 
 
 if __name__ == "__main__":
+    # eight agents: on the CPU the mesh's "data" axis is eight host
+    # devices (read when the backend starts, so before any jax work)
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

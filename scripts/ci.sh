@@ -43,8 +43,8 @@ python -m benchmarks.run --only mixing --budget smoke
 
 echo "=== tier 2: bench smoke (roofline: comm-fused mixing) ==="
 # modeled HBM traffic (3.0× / 2.5× reduction, unfused vs fused) plus
-# interpret-mode wall-clock validation of both gossip paths; rerun
-# with REPRO_PALLAS_INTERPRET=0 on a TPU to measure compiled kernels
+# interpret-mode wall-clock validation of both gossip paths (on a TPU
+# the same command runs the compiled kernels: the platform decides)
 python -m benchmarks.run --only roofline --budget smoke
 
 echo "=== tier 2: bench smoke (compressed gossip) ==="

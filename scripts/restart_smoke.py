@@ -24,7 +24,10 @@ all 4 jobs bit-exactly vs an uncheckpointed baseline loop.
 
 The subprocess boundary is the point: the resumed engine shares no
 process state (no compile cache, no Python objects) with the crashed
-one — everything it knows came off disk.
+one — everything it knows came off disk.  The parent only orchestrates
+and never touches JAX: the crash phase and the resume phase (restore,
+uninterrupted baseline, bitwise comparison) are each a child process,
+one at a time, so on a chip each phase can take the device.
 """
 from __future__ import annotations
 
@@ -89,26 +92,11 @@ def crash_admission_phase(ckpt_dir: str) -> int:
     return 1
 
 
-def _spawn_crash(phase: str, ckpt_dir: str) -> None:
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--phase", phase,
-         ckpt_dir],
-        env={**os.environ,
-             "PYTHONPATH": os.pathsep.join(
-                 [os.path.join(os.path.dirname(__file__), "..", "src"),
-                  os.environ.get("PYTHONPATH", "")])})
-    assert proc.returncode == CRASH_EXIT, \
-        f"{phase} phase exited {proc.returncode}, wanted {CRASH_EXIT}"
-    left = sorted(os.listdir(ckpt_dir))
-    assert left, f"crashed {phase} run left no checkpoints behind"
-    print(f"{phase} phase left {len(left)} checkpoint files")
-
-
-def _wave_smoke() -> None:
-    ckpt_dir = tempfile.mkdtemp(prefix="restart_smoke_")
-    _spawn_crash("crash", ckpt_dir)
-
-    # resume in a fresh engine: everything it knows came off disk
+def resume_phase(ckpt_dir: str) -> int:
+    """A fresh engine restores the crashed wave run and must match an
+    uninterrupted baseline bit-exactly."""
+    import numpy as np
+    from repro.serve import ServeEngine
     eng = _engine(ckpt_dir)
     results = eng.run()
     assert eng.stats.restarts == 1, \
@@ -118,12 +106,9 @@ def _wave_smoke() -> None:
         "completed run must clear its checkpoints"
 
     # uninterrupted baseline, clean engine, no checkpoint dir
-    from repro.serve import ServeEngine
     base = ServeEngine(chunk_rounds=4, max_width=4, hp_mode="traced")
     base.submit(_specs())
     baseline = {r.job_id: r for r in base.run()}
-
-    import numpy as np
     for r in results:
         b = baseline[r.job_id]
         assert np.array_equal(r.x, b.x) and np.array_equal(r.y, b.y), \
@@ -132,14 +117,14 @@ def _wave_smoke() -> None:
             f"{r.job_id}: rounds/sends mismatch after resume"
     print(f"restart smoke OK: {JOBS} jobs bit-exact after "
           f"kill -> restore -> resume (restarts=1)")
-    os.rmdir(ckpt_dir)
+    return 0
 
 
-def _admission_smoke() -> None:
-    ckpt_dir = tempfile.mkdtemp(prefix="restart_smoke_adm_")
-    _spawn_crash("crash-admission", ckpt_dir)
-
-    # the fresh loop must see the never-admitted jobs in its queue
+def resume_admission_phase(ckpt_dir: str) -> int:
+    """A fresh loop recovers the in-flight and the never-admitted jobs
+    and must match an uncheckpointed baseline loop bit-exactly."""
+    import numpy as np
+    from repro.serve.admission import AdmissionLoop
     loop = _loop(ckpt_dir)
     loop._maybe_restore()
     queued = loop.queue.job_ids()
@@ -152,13 +137,10 @@ def _admission_smoke() -> None:
     assert not os.listdir(ckpt_dir), \
         "drained loop must clear its checkpoints"
 
-    from repro.serve.admission import AdmissionLoop
     base = AdmissionLoop(chunk_rounds=4, max_width=2, bucket_width=2,
                          hp_mode="traced")
     base.submit(_specs())
     baseline = {r.job_id: r for r in base.run()}
-
-    import numpy as np
     for jid, b in baseline.items():
         r = loop.result(jid)
         assert np.array_equal(np.asarray(r.x), np.asarray(b.x)) \
@@ -168,19 +150,48 @@ def _admission_smoke() -> None:
             f"{jid}: rounds/sends mismatch after resume"
     print(f"admission restart smoke OK: {JOBS} jobs (2 in flight, "
           f"2 queued-unadmitted) bit-exact after kill -> restore")
+    return 0
+
+
+PHASES = {"crash": crash_phase,
+          "crash-admission": crash_admission_phase,
+          "resume": resume_phase,
+          "resume-admission": resume_admission_phase}
+
+
+def _spawn(phase: str, ckpt_dir: str) -> int:
+    return subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--phase", phase,
+         ckpt_dir],
+        env={**os.environ,
+             "PYTHONPATH": os.pathsep.join(
+                 [os.path.join(os.path.dirname(__file__), "..", "src"),
+                  os.environ.get("PYTHONPATH", "")])}).returncode
+
+
+def _crash_then_resume(crash: str, resume: str, prefix: str) -> None:
+    ckpt_dir = tempfile.mkdtemp(prefix=prefix)
+    rc = _spawn(crash, ckpt_dir)
+    if rc != CRASH_EXIT:
+        raise RuntimeError(f"{crash} phase exited {rc}, wanted {CRASH_EXIT}")
+    left = sorted(os.listdir(ckpt_dir))
+    if not left:
+        raise RuntimeError(f"crashed {crash} run left no checkpoints")
+    print(f"{crash} phase left {len(left)} checkpoint files")
+    rc = _spawn(resume, ckpt_dir)
+    if rc != 0:
+        raise RuntimeError(f"{resume} phase exited {rc}")
     os.rmdir(ckpt_dir)
 
 
 def main() -> int:
-    _wave_smoke()
-    _admission_smoke()
+    _crash_then_resume("crash", "resume", "restart_smoke_")
+    _crash_then_resume("crash-admission", "resume-admission",
+                       "restart_smoke_adm_")
     return 0
 
 
 if __name__ == "__main__":
     if len(sys.argv) == 4 and sys.argv[1] == "--phase":
-        if sys.argv[2] == "crash":
-            sys.exit(crash_phase(sys.argv[3]))
-        if sys.argv[2] == "crash-admission":
-            sys.exit(crash_admission_phase(sys.argv[3]))
+        sys.exit(PHASES[sys.argv[2]](sys.argv[3]))
     sys.exit(main())

@@ -325,7 +325,7 @@ BASELINE_SOLVERS = {
 
 def _baseline_shim(method: str, legacy_name: str, prob, net, *,
                    alpha, beta, K, M, x0, y0, seed,
-                   mixing="auto", mixing_interpret=True,
+                   mixing="auto", mixing_interpret=None,
                    mixing_dtype="f32", comm="identity", **method_kw):
     from repro.solve import solve
     from repro.solve._compat import warn_once

@@ -87,7 +87,7 @@ class DAGMConfig:
     dihgp: str = "dense"         # "dense" | "matrix_free" | "exact"
     curvature: float | None = None   # fixed λmax bound for matrix_free
     mixing: str = "auto"         # MixingOp backend (repro.topology)
-    mixing_interpret: bool = True    # Pallas interpret mode (CPU)
+    mixing_interpret: bool | None = None   # None: platform decides
     mixing_dtype: str = "f32"    # "f32" | "bf16" storage/gossip dtype
     comm: str = "identity"       # repro.comm gossip spec
 

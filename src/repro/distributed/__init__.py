@@ -1,38 +1,8 @@
 """Distributed tier: sharding rules, ring collectives, sharded DAGM.
 
-Also home of the version-compatible `shard_map` shim: newer jax exposes
-`jax.shard_map(..., axis_names=..., check_vma=...)`, while 0.4.x only
-has `jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)`.
-All shard_map users in this repo (dagm_sharded, models.moe, tests,
-examples) import it from here so the version split lives in one place.
+`shard_map` is jax's own (`jax.shard_map`); import it from there.
 """
 from __future__ import annotations
 
 from .sharding import (ShardingRules, make_rules, use_rules, shard,
                        current_rules, tree_param_sharding)
-
-import jax as _jax
-
-#: True when jax ships the stable `jax.shard_map` API.  Callers that
-#: need *partially-auto* shard_map (manual over some mesh axes, GSPMD
-#: auto over the rest) must check this: on jax 0.4.x the experimental
-#: `auto=` escape hatch check-fails in the SPMD partitioner for programs
-#: with sharding constraints inside the manual region.
-HAS_NATIVE_SHARD_MAP = hasattr(_jax, "shard_map")
-
-if HAS_NATIVE_SHARD_MAP:
-    shard_map = _jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                  axis_names=None, **kw):
-        """jax<0.5 fallback: check_vma → check_rep; `axis_names` (the
-        *manual* axes) → `auto` (its complement over the mesh)."""
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        else:
-            auto = frozenset()
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma,
-                              auto=auto, **kw)

@@ -25,9 +25,9 @@ from typing import Any, Callable, NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import shard_map
 from .collectives import (RingWeights, ring_laplacian, ring_laplacian_c,
                           ring_mix, ring_mix_c, taxpy, tdot, tnorm,
                           tscale, tsub, tadd)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -70,9 +72,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q/k/v: (B, S, H, hd) (same head count — GQA is pre-broadcast).
     Returns (B, S, H, hd)."""
+    interpret = pallas_interpret(interpret)
     B, S, H, hd = q.shape
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     scale = 1.0 / math.sqrt(hd)

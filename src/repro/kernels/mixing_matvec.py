@@ -25,7 +25,7 @@ Row-tiled halo variants (`*_halo`)
 The full-stripe layout caps n near 10⁴ ((n, bd)·4B·#blocks against the
 ~4 MB `VMEM_BUDGET_BYTES`).  The `*_halo` kernels tile the agent axis
 too — grid (n/bn, d/bd) — holding only a (bn, bd) row tile plus its
-neighbor halo: the operand stays in HBM (`pltpu.ANY`) and each program
+neighbor halo: the operand stays in HBM (`pl.ANY`) and each program
 DMAs three contiguous row ranges (low halo, main rows, high halo) into
 a VMEM scratch of (h_lo + bn + h_hi, bd) rows, after which every cyclic
 offset is a *static* sublane slice of the extended block.  Because
@@ -91,6 +91,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import pallas_interpret
+
 # Conservative per-program VMEM working-set budget (real cores have
 # ~16 MB, shared with pipelining double-buffers): the dispatch switches
 # from full-stripe to halo tiling when the resident blocks exceed this.
@@ -127,6 +129,14 @@ def _fmix32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+def _top24_uniform(bits: jnp.ndarray) -> jnp.ndarray:
+    """U[0,1) f32 from the top 24 bits of uint32 draws.  The shifted
+    value fits in 24 bits, so the detour through int32 is exact; Mosaic
+    has no direct uint32 -> f32 cast."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2.0 ** -24)
+
+
 def _hash_uniform(seed, rows, cols) -> jnp.ndarray:
     """U[0,1) f32 draws keyed on (seed, global row, global column).
 
@@ -138,8 +148,7 @@ def _hash_uniform(seed, rows, cols) -> jnp.ndarray:
         + cols.astype(jnp.uint32)
     h = _fmix32(base ^ (seed.astype(jnp.uint32) * jnp.uint32(0xC2B2AE3D)))
     h = _fmix32(h)
-    return (h >> jnp.uint32(8)).astype(jnp.float32) \
-        * jnp.float32(2.0 ** -24)
+    return _top24_uniform(h)
 
 
 def _block_uniform(seed, rows, cols, shape, prng: str, pids=()):
@@ -148,8 +157,7 @@ def _block_uniform(seed, rows, cols, shape, prng: str, pids=()):
     if prng == "pltpu":
         pltpu.prng_seed(seed, *pids)
         bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
-        return (bits >> jnp.uint32(8)).astype(jnp.float32) \
-            * jnp.float32(2.0 ** -24)
+        return _top24_uniform(bits)
     return jnp.broadcast_to(_hash_uniform(seed, rows, cols), shape)
 
 
@@ -276,7 +284,7 @@ def circulant_mix_matvec(y: jnp.ndarray, zp=None, scale=None, seed=None,
                          offsets: tuple[int, ...],
                          weights: tuple[float, ...],
                          laplacian: bool = False, bd: int = 128,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          comm: str | None = None, prng: str = "hash"):
     """W·Y (or (I−W)·Y) for circulant W; y: (n, d) with d % bd == 0.
 
@@ -289,6 +297,7 @@ def circulant_mix_matvec(y: jnp.ndarray, zp=None, scale=None, seed=None,
     the CHOCO replica `hat` (n, d); returns (out, payload) instead of
     out.  Neighbor rows are quantized in-kernel; the self term is exact.
     """
+    interpret = pallas_interpret(interpret)
     n, d = y.shape
     if d % bd:
         raise ValueError(f"d={d} not a multiple of bd={bd}")
@@ -441,7 +450,7 @@ def circulant_mix_matvec_halo(y: jnp.ndarray, zp=None, scale=None,
                               offsets: tuple[int, ...],
                               weights: tuple[float, ...],
                               laplacian: bool = False, bn: int = 256,
-                              bd: int = 128, interpret: bool = True,
+                              bd: int = 128, interpret: bool | None = None,
                               comm: str | None = None,
                               prng: str = "hash"):
     """Row-tiled twin of `circulant_mix_matvec`: grid (n/bn, d/bd), the
@@ -452,6 +461,7 @@ def circulant_mix_matvec_halo(y: jnp.ndarray, zp=None, scale=None,
     (position-keyed PRNG) so its payload is bitwise-identical too, and
     the mixed output agrees to ≤ 1 ulp (compiler FMA re-association).
     Requires bn | n and halo extents ≤ bn."""
+    interpret = pallas_interpret(interpret)
     n, d = y.shape
     if d % bd:
         raise ValueError(f"d={d} not a multiple of bd={bd}")
@@ -475,7 +485,7 @@ def circulant_mix_matvec_halo(y: jnp.ndarray, zp=None, scale=None,
     if fused is None:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0, grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((bn, bd), lambda i, j: (i, j)),
             scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2, 3))],
         )
@@ -490,7 +500,7 @@ def circulant_mix_matvec_halo(y: jnp.ndarray, zp=None, scale=None,
         raise ValueError("prng='pltpu' needs compiled TPU lowering; "
                          "interpret mode uses prng='hash'")
     vec = pl.BlockSpec((n, 1), lambda i, j, *_: (0, 0))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [vec, vec, hbm] + ([hbm] if ef else [])
     out_shape = jax.ShapeDtypeStruct((n, d), y.dtype)
     if ef:
@@ -599,7 +609,7 @@ def sparse_mix_matvec(y: jnp.ndarray, w_self: jnp.ndarray,
                       neighbors: jnp.ndarray, weights: jnp.ndarray,
                       zp=None, scale=None, seed=None, hat=None, *,
                       laplacian: bool = False, bd: int = 128,
-                      interpret: bool = True, comm: str | None = None,
+                      interpret: bool | None = None, comm: str | None = None,
                       prng: str = "hash"):
     """W·Y (or (I−W)·Y) for arbitrary sparse W; y: (n, d), d % bd == 0.
 
@@ -615,6 +625,7 @@ def sparse_mix_matvec(y: jnp.ndarray, w_self: jnp.ndarray,
     zp/scale operands + in-kernel uniforms), self term exact; ``+ef``
     additionally takes `hat` and returns (out, payload).
     """
+    interpret = pallas_interpret(interpret)
     n, d = y.shape
     if d % bd:
         raise ValueError(f"d={d} not a multiple of bd={bd}")
@@ -740,7 +751,7 @@ def sparse_mix_matvec_halo(y: jnp.ndarray, w_self: jnp.ndarray,
                            neighbors: jnp.ndarray, weights: jnp.ndarray,
                            zp=None, scale=None, seed=None, *,
                            laplacian: bool = False, bn: int = 256,
-                           bd: int = 128, interpret: bool = True,
+                           bd: int = 128, interpret: bool | None = None,
                            comm: str | None = None, prng: str = "hash"):
     """Row-tiled twin of `sparse_mix_matvec`: grid (n/bn, d/bd), the
     operand stays in HBM; each program DMAs its own (bn, bd) row block
@@ -750,6 +761,7 @@ def sparse_mix_matvec_halo(y: jnp.ndarray, w_self: jnp.ndarray,
     variants agree bitwise (comm-fused included, via the position-keyed
     PRNG).  Error-feedback comm is not lowered here (the EF payload
     write-back needs the full stripe) — MixingOp falls back for it."""
+    interpret = pallas_interpret(interpret)
     n, d = y.shape
     if d % bd:
         raise ValueError(f"d={d} not a multiple of bd={bd}")
@@ -772,7 +784,7 @@ def sparse_mix_matvec_halo(y: jnp.ndarray, w_self: jnp.ndarray,
                pltpu.VMEM((max(k, 1), bd), y.dtype),
                pltpu.SemaphoreType.DMA((k + 1,))]
     out_spec = pl.BlockSpec((bn, bd), lambda i, j, *_: (i, j))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     if fused is None:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=grid,
@@ -854,7 +866,7 @@ def circulant_neumann_step(h: jnp.ndarray, hvp_h: jnp.ndarray,
                            zp=None, scale=None, seed=None, *,
                            w_self: float, offsets: tuple[int, ...],
                            weights: tuple[float, ...], beta: float,
-                           bd: int = 128, interpret: bool = True,
+                           bd: int = 128, interpret: bool | None = None,
                            comm: str | None = None,
                            prng: str = "hash") -> jnp.ndarray:
     """One DIHGP Neumann iteration (Eq. 14), fused:
@@ -868,6 +880,7 @@ def circulant_neumann_step(h: jnp.ndarray, hvp_h: jnp.ndarray,
     runs the quantizer roundtrip in the same pass — the DIHGP hot loop
     keeps one traversal even under compressed gossip.
     """
+    interpret = pallas_interpret(interpret)
     n, d = h.shape
     if d % bd:
         raise ValueError(f"d={d} not a multiple of bd={bd}")
@@ -922,7 +935,7 @@ def circulant_neumann_step(h: jnp.ndarray, hvp_h: jnp.ndarray,
                                              "bd", "interpret"))
 def ring_laplacian_matvec(y: jnp.ndarray, *, w_self: float, w_edge: float,
                           bn: int = 8, bd: int = 128,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool | None = None) -> jnp.ndarray:
     """(I − W)·Y for ring W (compat wrapper over the circulant kernel);
     y: (n, d) with d % bd == 0.  `bn` is accepted for API compatibility
     but ignored: the column-stripe kernel no longer tiles the agent

@@ -1,17 +1,20 @@
 """Jit'd public entry points for the Pallas kernels.
 
 `pallas_mode(True)` (a context manager) switches the hot paths from the
-pure-jnp oracles (CPU default / dry-run path) to the Pallas kernels
-(TPU target; `interpret=True` executes them on CPU for validation) for
+pure-jnp oracles (CPU default / dry-run path) to the Pallas kernels for
 the duration of the `with` block, restoring the previous mode on exit —
 no state leaks between tests.  `use_pallas(...)` remains as the
 imperative form for scripts that flip the mode for a whole process.
 
-Whether Pallas runs in interpret mode defaults to True (CPU-safe) and
-can be overridden per process with ``REPRO_PALLAS_INTERPRET=0`` for
-real-hardware benchmark runs — `pallas_mode(True)` / `use_pallas(True)`
-with no explicit `interpret=` then compile for the actual TPU, so the
-same benchmark/test invocation works on both targets unchanged.
+Whether a Pallas kernel runs in interpret mode is decided in one place,
+`pallas_interpret()`: the platform decides — compiled on a TPU,
+interpreted everywhere else (the CPU) — unless a caller passes an
+explicit ``interpret=`` (tests).  Every kernel and every `interpret`
+field (`MixingSpec.interpret`, `MixingOp(interpret=)`) defaults to
+None, meaning "let the platform decide".  The kernels resolve None
+inside their jit, so the answer may depend only on the platform, which
+cannot change within a process: no global setting can make a cached
+trace stale.
 
 `repro.topology.ops.MixingOp` consults `pallas_enabled()` so that
 flipping this one switch upgrades every circulant / sparse-gather
@@ -20,32 +23,20 @@ mixing mat-vec in the DAGM hot loop to the Pallas backend as well.
 from __future__ import annotations
 
 import contextlib
-import os
 
+import jax
 import jax.numpy as jnp
 
 from . import ref
-from .flash_attention import flash_attention
-from .mixing_matvec import ring_laplacian_matvec
-from .rwkv6_scan import rwkv6_scan
 
 _USE_PALLAS = False
-# None = not explicitly set -> fall back to the env default lazily, so
-# REPRO_PALLAS_INTERPRET is honored even when set after import
-_INTERPRET: bool | None = None
 
 
-def _env_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
-def use_pallas(enabled: bool, interpret: bool | None = None) -> None:
+def use_pallas(enabled: bool) -> None:
     """Imperative mode switch (whole-process scripts; tests should use
-    `pallas_mode`).  `interpret=None` defers to REPRO_PALLAS_INTERPRET
-    (default interpret=True, i.e. CPU-safe)."""
-    global _USE_PALLAS, _INTERPRET
+    `pallas_mode`)."""
+    global _USE_PALLAS
     _USE_PALLAS = enabled
-    _INTERPRET = interpret
 
 
 def pallas_enabled() -> tuple[bool, bool]:
@@ -53,28 +44,31 @@ def pallas_enabled() -> tuple[bool, bool]:
     return _USE_PALLAS, pallas_interpret()
 
 
-def pallas_interpret() -> bool:
-    """Effective interpret flag: the explicit `use_pallas`/`pallas_mode`
-    setting if given, else the REPRO_PALLAS_INTERPRET env default."""
-    return _env_interpret() if _INTERPRET is None else _INTERPRET
+def pallas_interpret(interpret: bool | None = None) -> bool:
+    """Effective interpret flag: an explicit `interpret` wins, else the
+    platform — compiled on a TPU, interpreted on any other backend."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
 
 
 @contextlib.contextmanager
-def pallas_mode(enabled: bool, interpret: bool | None = None):
+def pallas_mode(enabled: bool):
     """Scoped Pallas toggle: `with pallas_mode(True): ...` runs the
-    block with Pallas kernels enabled and restores the previous
-    (enabled, interpret) state on exit, exception or not."""
-    global _USE_PALLAS, _INTERPRET
-    saved = (_USE_PALLAS, _INTERPRET)
-    _USE_PALLAS, _INTERPRET = enabled, interpret
+    block with Pallas kernels enabled and restores the previous state
+    on exit, exception or not."""
+    global _USE_PALLAS
+    saved = _USE_PALLAS
+    _USE_PALLAS = enabled
     try:
         yield
     finally:
-        _USE_PALLAS, _INTERPRET = saved
+        _USE_PALLAS = saved
 
 
 def ring_laplacian(y, w_self: float, w_edge: float):
     """(I−W)Y for ring W — DAGM/DIHGP mixing primitive; y (n, d)."""
+    from .mixing_matvec import ring_laplacian_matvec
     # dtype-aware sublane minimum — must agree with MixingOp._pallas_ok
     # (bf16 stripes need 16 sublanes on TPU, f32 needs 8)
     sub = {jnp.dtype(jnp.float32): 8, jnp.dtype(jnp.bfloat16): 16}.get(
@@ -88,6 +82,7 @@ def ring_laplacian(y, w_self: float, w_edge: float):
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0):
     """Softmax attention (same-head-count q/k/v)."""
+    from .flash_attention import flash_attention
     if _USE_PALLAS and q.shape[1] % 128 == 0:
         return flash_attention(q, k, v, causal=causal, window=window,
                                interpret=pallas_interpret())
@@ -96,6 +91,7 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0):
 
 def wkv(r, k, v, logw, u, *, chunk: int = 64):
     """RWKV6 WKV mix."""
+    from .rwkv6_scan import rwkv6_scan
     if _USE_PALLAS and r.shape[1] % chunk == 0:
         return rwkv6_scan(r, k, v, logw, u, chunk=chunk,
                           interpret=pallas_interpret()).astype(jnp.float32)

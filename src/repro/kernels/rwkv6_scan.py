@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import pallas_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, S_scr, *, chunk):
     ci = pl.program_id(1)
@@ -49,10 +51,11 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, S_scr, *, chunk):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r, k, v, logw, u, *, chunk: int = 64,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """r/k/v/logw: (B, T, H, hd); u: (H, hd).  Returns out (B, T, H, hd).
 
     T % chunk == 0 required (pad upstream)."""
+    interpret = pallas_interpret(interpret)
     B, T, H, hd = r.shape
     assert T % chunk == 0, (T, chunk)
     nc = T // chunk

@@ -40,8 +40,7 @@ from repro.distributed.dagm_sharded import make_sharded_dagm
 from repro.solve import sharded_spec
 from repro.distributed.sharding import make_rules
 from repro.launch.dryrun import collective_bytes_from_hlo
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh, roofline_terms
 from repro.models import build_model
 
 N_DOMAINS = 8
@@ -164,10 +163,8 @@ def run(arch: str, *, multi_pod: bool = False, seq_len: int = 4096,
                  + getattr(mem, "argument_size_in_bytes", 0)
                  + getattr(mem, "output_size_in_bytes", 0)
                  - getattr(mem, "alias_size_in_bytes", 0))
-    terms = {"compute_s": flops / PEAK_FLOPS_BF16,
-             "memory_s": byts / HBM_BW,
-             "collective_s": sum(coll.values()) / ICI_BW}
-    bound = max(terms, key=terms.get)
+    terms = roofline_terms(flops, byts, sum(coll.values()))
+    bound = terms.pop("bottleneck")
     out = {"arch": arch, "mesh": mesh_name, "M": M, "U": U,
            "comm_dtype": comm_dtype, "param_dtype": param_dtype,
            "mix_every": mix_every, "seq_len": seq_len,

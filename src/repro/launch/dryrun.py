@@ -32,8 +32,7 @@ from repro.distributed.sharding import (make_rules, tree_param_sharding,
 from repro.launch.costs import (affine_correct, depth_pair,
                                 flops_estimate, model_flops_convention,
                                 reduced_depth)
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh, roofline_terms
 from repro.models import build_model
 from repro.models.steps import make_decode_step, make_prefill_step, \
     make_train_step
@@ -158,13 +157,7 @@ class DryRunResult:
             sum(self.collective_bytes.values())
         flops = self.flops_corrected or self.flops
         byts = self.bytes_corrected or self.hbm_bytes_accessed
-        terms = {
-            "compute_s": flops / PEAK_FLOPS_BF16,
-            "memory_s": byts / HBM_BW,
-            "collective_s": coll / ICI_BW,
-        }
-        terms["bottleneck"] = max(terms, key=terms.get)
-        return terms
+        return roofline_terms(flops, byts, coll)
 
 
 _COLL_RE = re.compile(

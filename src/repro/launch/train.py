@@ -24,6 +24,7 @@ from repro.checkpoint import latest_step, restore_checkpoint, \
     save_checkpoint
 from repro.configs import get_config
 from repro.data import TokenDataConfig, make_token_batch
+from repro.launch.mesh import make_host_mesh
 from repro.distributed.sharding import make_rules, tree_param_sharding, \
     use_rules
 from repro.models import build_model
@@ -55,9 +56,7 @@ def main(argv=None):
         cfg = cfg.reduced()
     model = build_model(cfg)
 
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // args.model_parallel,
-                          args.model_parallel), ("data", "model"))
+    mesh = make_host_mesh(model=args.model_parallel)
     rules = make_rules(cfg, mesh)
     print(f"[train] {cfg.name}: {model.param_count()/1e6:.1f}M params, "
           f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
@@ -112,4 +111,6 @@ def main(argv=None):
 
 if __name__ == "__main__":
     import sys
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -69,18 +69,11 @@ def _moe_grouped_shard_map(p: Params, x, cfg):
     installed."""
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.distributed import HAS_NATIVE_SHARD_MAP, shard_map
+    from jax import shard_map
     from repro.distributed.sharding import current_rules
 
     rules = current_rules()
     if rules is None:
-        return _moe_grouped(p, x, cfg)
-    if not HAS_NATIVE_SHARD_MAP:
-        # This impl needs partially-auto shard_map (manual batch axes,
-        # auto "model" axis for the expert weights); jax 0.4.x's
-        # experimental `auto=` check-fails in the SPMD partitioner on
-        # this program, so use the semantically identical batched
-        # grouped dispatch there (same routing, same gradients).
         return _moe_grouped(p, x, cfg)
     batch_axes = rules.table.get("batch")
     if not batch_axes:

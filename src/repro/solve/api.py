@@ -174,17 +174,21 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
         # very same traced operands — batched traced-hp runs are
         # bit-exact with this solo program.  (The closure itself is
         # per-call: solo solve() does not cache compiles across
-        # invocations; sweeps belong on tier="serve".)
+        # invocations; sweeps belong on tier="serve".)  The problem
+        # data is an argument too: closed over, it would be compiled
+        # into the program as constants (1.4 GB for a 64-agent
+        # quadratic with d2 = 1024), which is slow to compile and too
+        # big for the persistent compile cache.
         @jax.jit
-        def run(carry, hp, masks):
-            return dagm_run_chunk(prob, W, spec, carry, spec.K,
-                                  metrics_fn, hp=hp, masks=masks,
+        def run(carry, hp, masks, data):
+            return dagm_run_chunk(prob.with_data(data), W, spec, carry,
+                                  spec.K, metrics_fn, hp=hp, masks=masks,
                                   recorder=recorder)
 
         t0 = tr.now_us()
         out = run(
             carry0, RoundHP(*(jnp.asarray(a, jnp.float32) for a in hp)),
-            masks)
+            masks, prob.data)
         t_disp = tr.now_us()
         if tr.enabled:
             # the call above returned once tracing+compile+dispatch

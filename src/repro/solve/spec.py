@@ -131,7 +131,8 @@ class MixingSpec:
     """(I−W)·Y execution backend — see repro.topology.ops.MixingOp."""
     backend: str = "auto"       # "auto" | "dense" | "circulant[_pallas]"
     #                             | "sparse_gather[_pallas]"
-    interpret: bool = True      # Pallas interpret mode (CPU)
+    interpret: bool | None = None   # Pallas interpret mode; None: the
+    #                                 platform decides (kernels.ops)
     dtype: str = "f32"          # "f32" | "bf16" storage/gossip dtype
 
 
@@ -331,7 +332,8 @@ def mixing_kwargs(cfg) -> dict:
 def dagm_spec(alpha=1e-2, beta=1e-2, gamma=None, K: int = 100,
               M: int = 10, U: int = 3, dihgp: str = "dense",
               curvature: float | None = None, mixing: str = "auto",
-              mixing_interpret: bool = True, mixing_dtype: str = "f32",
+              mixing_interpret: bool | None = None,
+              mixing_dtype: str = "f32",
               comm: str = "identity", tier: str = "reference",
               faults=None) -> SolverSpec:
     """Convenience constructor mirroring the old DAGMConfig kwargs —

@@ -258,7 +258,8 @@ class MixingOp:
     """
 
     def __init__(self, W, *, backend: str = "auto",
-                 interpret: bool = True, name: str = "network",
+                 interpret: bool | None = None,
+                 name: str = "network",
                  dtype: str = "f32", comm: str = "identity"):
         from repro.comm import CommLedger, parse_comm_spec
         if backend not in BACKENDS:
@@ -817,7 +818,7 @@ class MaskedMixingOp(MixingOp):
 
 
 def make_mixing_op(net: "Network", backend: str = "auto",
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    dtype: str = "f32",
                    comm: str = "identity") -> MixingOp:
     """Build the execution backend for a validated Network."""

@@ -15,7 +15,7 @@ What is locked down here (ISSUE 7):
   * n = 4096 (full stripe over the VMEM budget) auto-switches to the
     halo tier and stays correct.
   * Fallbacks warn once per op/shape and never raise; `pallas_mode`
-    restores state; REPRO_PALLAS_INTERPRET is honored.
+    restores state; the platform decides interpret mode.
 """
 import warnings
 
@@ -354,12 +354,12 @@ def test_fallback_warns_once_per_shape():
 
 
 # ---------------------------------------------------------------------------
-# pallas_mode / env override
+# pallas_mode / interpret rule
 # ---------------------------------------------------------------------------
 
 def test_pallas_mode_restores_state():
     before = kops.pallas_enabled()
-    with pallas_mode(True, interpret=True):
+    with pallas_mode(True):
         assert kops.pallas_enabled() == (True, True)
         with pallas_mode(False):
             assert kops.pallas_enabled()[0] is False
@@ -373,16 +373,16 @@ def test_pallas_mode_restores_state():
 
 
 def test_env_override_interpret(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    """The platform decides interpret mode: interpreted on the CPU,
+    compiled on a TPU; an explicit `interpret` argument wins over the
+    platform."""
+    assert jax.default_backend() == "cpu"
     with pallas_mode(True):
         assert kops.pallas_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+        assert kops.pallas_enabled() == (True, True)
+        assert kops.pallas_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pallas_mode(True):
         assert kops.pallas_interpret() is False
         assert kops.pallas_enabled() == (True, False)
-        # an explicit interpret= wins over the env
-        with pallas_mode(True, interpret=True):
-            assert kops.pallas_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    with pallas_mode(True):
-        assert kops.pallas_interpret() is True
+        assert kops.pallas_interpret(True) is True

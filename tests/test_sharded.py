@@ -22,7 +22,7 @@ sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed import shard_map
+from jax import shard_map
 
 from repro.core import quadratic_bilevel, DAGMConfig, dagm_run
 from repro.core.mixing import mix_apply
@@ -92,7 +92,7 @@ sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed import shard_map
+from jax import shard_map
 
 from repro.core import quadratic_bilevel
 from repro.distributed.collectives import RingWeights, ring_mix
@@ -178,7 +178,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.comm import channel_init, parse_comm_spec
 from repro.core import quadratic_bilevel
-from repro.distributed import shard_map
+from jax import shard_map
 from repro.distributed.collectives import RingWeights, ring_mix, ring_mix_c
 from repro.distributed.dagm_sharded import (ShardedDAGMConfig,
                                             make_sharded_dagm,
@@ -278,7 +278,8 @@ from repro.distributed.sharding import make_rules, use_rules
 
 cfg0 = dataclasses.replace(get_config("granite-moe-3b-a800m").reduced(),
                            capacity_factor=8.0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = init_moe(Maker(jax.random.PRNGKey(0), jnp.float32), cfg0)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg0.d_model))
 
@@ -325,10 +326,11 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.mixing import mix_apply
-from repro.distributed import shard_map
+from jax import shard_map
 from repro.distributed.collectives import RingWeights, ring_mix
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 w = RingWeights.metropolis_ring(8)
 z = jax.random.normal(jax.random.PRNGKey(0), (8, 5))
 def local(zz):

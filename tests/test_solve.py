@@ -324,8 +324,9 @@ def test_mixing_spec_roundtrip_through_legacy_config():
                          comm="int8+ef", dihgp="matrix_free",
                          curvature=5.0)
     spec = as_solver_spec(cfg)
+    # interpret unset on both sides: the platform decides
     assert spec.mixing == MixingSpec(backend="circulant",
-                                     interpret=True, dtype="bf16")
+                                     interpret=None, dtype="bf16")
     assert spec.comm.spec == "int8+ef"
     assert spec.K == 7 and spec.curvature == 5.0
     sched = spec.schedule.materialize(7)

@@ -77,3 +77,36 @@ def test_collective_parser():
     assert got["all-gather"] == 8 * 128 * 2
     assert got["all-reduce"] == 256 * 4
     assert got["collective-permute"] == 2 * (2 * 2 * 4)
+
+
+def test_chip_peaks_table():
+    """One peak table keyed by device_kind; an unknown chip is an
+    error, never a default."""
+    from repro.launch.mesh import chip_peaks, roofline_terms
+    peaks = chip_peaks("TPU v5 lite")
+    assert peaks["hbm_bw"] == 819e9
+    assert peaks["ici_bw"] == 1600e9 / 8          # 1,600 Gbit/s per chip
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("TPU v4")
+    terms = roofline_terms(197e12, 2 * 819e9, 0.0)
+    assert terms["compute_s"] == 1.0 and terms["memory_s"] == 2.0
+    assert terms["bottleneck"] == "memory_s"
+
+
+def test_compile_cache_placement(monkeypatch):
+    """A JAX_COMPILATION_CACHE_DIR set from outside wins untouched;
+    otherwise the cache sits at one fixed path in the checkout."""
+    from repro import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "outside-cache")
+        assert compile_cache.enable_compile_cache() == "outside-cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CACHE_DIR)
+        assert path.endswith(".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path   # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
