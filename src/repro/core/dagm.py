@@ -172,7 +172,8 @@ def hypergrad_estimate(prob: BilevelProblem, W, cfg,
         hp = constant_round_hp(cfg)
     if curvature is None:
         curvature = cfg.curvature
-    h = _dihgp_h(prob, W, cfg, x, y, hp.beta, curvature)
+    with jax.named_scope("dihgp"):
+        h = _dihgp_h(prob, W, cfg, x, y, hp.beta, curvature)
     return laplacian_apply(W, x) * hp.gamma + prob.grad_x_f(x, y) \
         + hp.beta * prob.cross_xy_g_times(x, y, h)
 
@@ -201,20 +202,21 @@ def hypergrad_estimate_c(prob: BilevelProblem, W, cfg,
         hp = constant_round_hp(cfg)
     if curvature is None:
         curvature = cfg.curvature
-    if cfg.dihgp == "dense":
-        h, h_st = dihgp_dense_c(prob, W, hp.beta, x, y, cfg.U, h_st)
-    elif cfg.dihgp == "matrix_free":
-        hvp = lambda v: prob.hvp_yy_g(x, y, v)
-        curv = None if curvature is None else \
-            jnp.full((prob.n,), curvature, jnp.float32)
-        h, h_st = dihgp_matrix_free_c(hvp, prob.grad_y_f(x, y), W,
-                                      hp.beta, cfg.U, h_st,
-                                      curvature=curv)
-    elif cfg.dihgp == "exact":
-        from .penalty import exact_ihgp
-        h = exact_ihgp(prob, W, hp.beta, x, y)
-    else:
-        raise ValueError(f"unknown dihgp backend {cfg.dihgp!r}")
+    with jax.named_scope("dihgp"):
+        if cfg.dihgp == "dense":
+            h, h_st = dihgp_dense_c(prob, W, hp.beta, x, y, cfg.U, h_st)
+        elif cfg.dihgp == "matrix_free":
+            hvp = lambda v: prob.hvp_yy_g(x, y, v)
+            curv = None if curvature is None else \
+                jnp.full((prob.n,), curvature, jnp.float32)
+            h, h_st = dihgp_matrix_free_c(hvp, prob.grad_y_f(x, y), W,
+                                          hp.beta, cfg.U, h_st,
+                                          curvature=curv)
+        elif cfg.dihgp == "exact":
+            from .penalty import exact_ihgp
+            h = exact_ihgp(prob, W, hp.beta, x, y)
+        else:
+            raise ValueError(f"unknown dihgp backend {cfg.dihgp!r}")
     lap_x, x_st = laplacian_apply_c(W, x, x_st)
     return lap_x * hp.gamma + prob.grad_x_f(x, y) \
         + hp.beta * prob.cross_xy_g_times(x, y, h), h_st, x_st
@@ -229,21 +231,25 @@ def dagm_outer_step(prob: BilevelProblem, W, cfg,
         hp = constant_round_hp(cfg)
     def inner(t, yy):
         return inner_dgd_step(prob, W, hp.beta, x, yy)         # Eq. 16
-    y_tilde = jax.lax.fori_loop(0, cfg.M, inner, y)            # lines 4–9
+    with jax.named_scope("inner_dgd"):
+        y_tilde = jax.lax.fori_loop(0, cfg.M, inner, y)        # lines 4–9
 
-    d = hypergrad_estimate(prob, W, cfg, x, y_tilde, hp=hp,
-                           curvature=curvature)                # lines 10–12
-    x_next = x - hp.alpha * d                                  # line 13
-    # custom metrics callbacks receive W exactly as configured (a
-    # MixingOp under dagm_run, or whatever array the caller passed) —
-    # use mixing.as_matrix(W) inside the callback for raw entries.
-    # default_metrics never read W, so the default path no longer
-    # threads an n×n matrix through the jitted scan at all.
-    if metrics_fn is None:
-        metrics = default_metrics(prob, x, y_tilde)
-    else:
-        metrics = metrics_fn(prob, W, x, y_tilde)
-    metrics["hypergrad_est_norm_sq"] = jnp.sum(d ** 2)
+    # the outer step's scope also holds the round's metrics: they are
+    # evaluated at the same (x, ỹ) and share its forward passes
+    with jax.named_scope("outer_step"):
+        d = hypergrad_estimate(prob, W, cfg, x, y_tilde, hp=hp,
+                               curvature=curvature)            # lines 10–12
+        x_next = x - hp.alpha * d                              # line 13
+        # custom metrics callbacks receive W exactly as configured (a
+        # MixingOp under dagm_run, or whatever array the caller
+        # passed) — use mixing.as_matrix(W) inside the callback for raw
+        # entries.  default_metrics never read W, so the default path
+        # no longer threads an n×n matrix through the jitted scan.
+        if metrics_fn is None:
+            metrics = default_metrics(prob, x, y_tilde)
+        else:
+            metrics = metrics_fn(prob, W, x, y_tilde)
+        metrics["hypergrad_est_norm_sq"] = jnp.sum(d ** 2)
     return x_next, y_tilde, metrics
 
 
@@ -283,18 +289,19 @@ def dagm_outer_step_c(prob: BilevelProblem, W, cfg,
     def inner(t, carry):
         yy, st = carry
         return inner_dgd_step_c(prob, W, hp.beta, x, yy, st)    # Eq. 16
-    y_tilde, y_st = jax.lax.fori_loop(0, cfg.M, inner,
-                                      (y, cs["inner_y"]))       # lines 4–9
-    d, h_st, x_st = hypergrad_estimate_c(prob, W, cfg, x, y_tilde,
-                                         cs["dihgp_h"],
-                                         cs["outer_x"], hp=hp,
-                                         curvature=curvature)   # lines 10–12
-    x_next = x - hp.alpha * d                                   # line 13
-    if metrics_fn is None:
-        metrics = default_metrics(prob, x, y_tilde)
-    else:
-        metrics = metrics_fn(prob, W, x, y_tilde)
-    metrics["hypergrad_est_norm_sq"] = jnp.sum(d ** 2)
+    with jax.named_scope("inner_dgd"):
+        y_tilde, y_st = jax.lax.fori_loop(0, cfg.M, inner,
+                                          (y, cs["inner_y"]))   # lines 4–9
+    with jax.named_scope("outer_step"):
+        d, h_st, x_st = hypergrad_estimate_c(
+            prob, W, cfg, x, y_tilde, cs["dihgp_h"], cs["outer_x"],
+            hp=hp, curvature=curvature)                         # lines 10–12
+        x_next = x - hp.alpha * d                               # line 13
+        if metrics_fn is None:
+            metrics = default_metrics(prob, x, y_tilde)
+        else:
+            metrics = metrics_fn(prob, W, x, y_tilde)
+        metrics["hypergrad_est_norm_sq"] = jnp.sum(d ** 2)
     return x_next, y_tilde, metrics, \
         {"inner_y": y_st, "dihgp_h": h_st, "outer_x": x_st}
 

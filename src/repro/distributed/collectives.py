@@ -80,7 +80,8 @@ def ring_mix(tree, axis_name: str, w: RingWeights, comm_dtype=None):
             recv = ppermute_shift(send, axis_name, offset, w.n)
             out = out + weight * recv.astype(x.dtype)
         return out
-    return jax.tree.map(mix_leaf, tree)
+    with jax.named_scope("gossip"):
+        return jax.tree.map(mix_leaf, tree)
 
 
 def ring_laplacian(tree, axis_name: str, w: RingWeights, comm_dtype=None):
@@ -104,6 +105,11 @@ def ring_mix_c(tree, axis_name: str, w: RingWeights, policy, st):
     before the ppermute — the packed wire is the ROADMAP fused
     quantize+gather Pallas kernel.  `st` is a `ChannelState` whose
     `hat` mirrors the tree structure (see `sharded_channel_init`)."""
+    with jax.named_scope("gossip"):
+        return _ring_mix_c(tree, axis_name, w, policy, st)
+
+
+def _ring_mix_c(tree, axis_name: str, w: RingWeights, policy, st):
     from repro.comm import compressed_payload_local
     if policy.is_identity:
         return ring_mix(tree, axis_name, w), st.bump()

@@ -281,11 +281,12 @@ def dagm_local_round(g_fn: Callable, f_fn: Callable,
         else:
             mixed, st = ring_mix_c(yy, axis, w, pol, st)
         return taxpy(hp.neg_beta, grad_y_g(x, yy, batch), mixed), st
-    if cfg.unroll_loops:
-        for t in range(cfg.M):
-            y, st_y = inner(t, (y, st_y))
-    else:
-        y, st_y = jax.lax.fori_loop(0, cfg.M, inner, (y, st_y))
+    with jax.named_scope("inner_dgd"):
+        if cfg.unroll_loops:
+            for t in range(cfg.M):
+                y, st_y = inner(t, (y, st_y))
+        else:
+            y, st_y = jax.lax.fori_loop(0, cfg.M, inner, (y, st_y))
 
     # ---- DIHGP (Alg. 1, scalar-preconditioned, matrix-free) ----
     def hvp(v):
@@ -295,47 +296,52 @@ def dagm_local_round(g_fn: Callable, f_fn: Callable,
         lap, st = ring_laplacian_c(hh, axis, w, pol, st)
         return taxpy(hp.beta, hvp(hh), lap), st
 
-    p = grad_y_f(x, y, batch)
-    h = tscale(hp.neg_inv_d, p)
     def dihgp_iter(_, carry):
         hh, st = carry
         bh_mix, st = H_apply(hh, st)
         bh = tsub(tscale(hp.d, hh), bh_mix)            # B̃ h
         return tscale(hp.inv_d, tsub(bh, p)), st
-    if cfg.unroll_loops:
-        for _ in range(cfg.U):
-            h, st_h = dihgp_iter(0, (h, st_h))
-    else:
-        h, st_h = jax.lax.fori_loop(0, cfg.U, dihgp_iter, (h, st_h))
+    with jax.named_scope("dihgp"):
+        p = grad_y_f(x, y, batch)
+        h = tscale(hp.neg_inv_d, p)
+        if cfg.unroll_loops:
+            for _ in range(cfg.U):
+                h, st_h = dihgp_iter(0, (h, st_h))
+        else:
+            h, st_h = jax.lax.fori_loop(0, cfg.U, dihgp_iter,
+                                        (h, st_h))
 
-    # ---- outer hyper-gradient (Eq. 17b) and step ----
+    # ---- outer hyper-gradient (Eq. 17b) and step, with the round's
+    # metrics (evaluated at the same (x, ỹ)) ----
     def cross(xx):
         return tdot(jax.grad(g_fn, argnums=1)(xx, y, batch), h)
-    cross_term = jax.grad(cross)(x)
 
-    d_dir = taxpy(hp.beta, cross_term, grad_x_f(x, y, batch))
-    mixed_x, st_x = ring_mix_c(x, axis, w, pol, st_x)
-    x_new = taxpy(hp.neg_alpha, d_dir, mixed_x)        # Ẃx − α(...)
+    with jax.named_scope("outer_step"):
+        cross_term = jax.grad(cross)(x)
+        d_dir = taxpy(hp.beta, cross_term, grad_x_f(x, y, batch))
+        mixed_x, st_x = ring_mix_c(x, axis, w, pol, st_x)
+        x_new = taxpy(hp.neg_alpha, d_dir, mixed_x)    # Ẃx − α(...)
 
-    metrics = {
-        "outer_loss": f_fn(x, y, batch),
-        "inner_loss": g_fn(x, y, batch),
-        "hypergrad_norm": tnorm(d_dir),
-        "consensus_x": tnorm(ring_laplacian(x, cfg.axis, w)),
-        # gossip exchanges, from the traced channel counters (feeds
-        # sharded_comm_ledger for the byte accounting): this round's
-        # when channels reopen per round, cumulative under persist_ef
-        "comm_sends": (st_y.sends + st_h.sends + st_x.sends)
-        .astype(jnp.float32),
-    }  # consensus metric uses full-precision exchange (diagnostic)
-    if flight_gamma is not None:
-        gamma = jnp.asarray(flight_gamma, jnp.float32)
-        gap_t = tadd(tscale(gamma, ring_laplacian(x, cfg.axis, w)),
-                     d_dir)
-        xbar = jax.tree.map(lambda a: jax.lax.pmean(a, axis), x)
-        metrics["flight_gap_sq"] = tdot(gap_t, gap_t).real
-        diff = tsub(x, xbar)
-        metrics["flight_consensus_sq"] = tdot(diff, diff).real
+        metrics = {
+            "outer_loss": f_fn(x, y, batch),
+            "inner_loss": g_fn(x, y, batch),
+            "hypergrad_norm": tnorm(d_dir),
+            "consensus_x": tnorm(ring_laplacian(x, cfg.axis, w)),
+            # gossip exchanges, from the traced channel counters (feeds
+            # sharded_comm_ledger for the byte accounting): this
+            # round's when channels reopen per round, cumulative under
+            # persist_ef
+            "comm_sends": (st_y.sends + st_h.sends + st_x.sends)
+            .astype(jnp.float32),
+        }  # consensus metric uses full-precision exchange (diagnostic)
+        if flight_gamma is not None:
+            gamma = jnp.asarray(flight_gamma, jnp.float32)
+            gap_t = tadd(tscale(gamma, ring_laplacian(x, cfg.axis, w)),
+                         d_dir)
+            xbar = jax.tree.map(lambda a: jax.lax.pmean(a, axis), x)
+            metrics["flight_gap_sq"] = tdot(gap_t, gap_t).real
+            diff = tsub(x, xbar)
+            metrics["flight_consensus_sq"] = tdot(diff, diff).real
     if channels is not None:
         return x_new, y, metrics, \
             {"inner_y": st_y, "dihgp_h": st_h, "outer_x": st_x}

@@ -2,8 +2,9 @@
 
 One subsystem, three layers, every tier emits into it:
 
-  spans     host-side phase/lifecycle tracing → Perfetto trace JSON
-            (`tracing()`, `span()`, `synthesize_round_spans`)
+  spans     host-side phase/lifecycle tracing → Perfetto trace JSON,
+            mirrored into the JAX profiler as `repro:<name>`
+            annotations (`tracing()`, `span()`)
   metrics   labeled counters/gauges/histograms adapting the existing
             CommLedger / EngineStats / fault-extras instruments, plus
             the shared `TraceCounter` retrace counter
@@ -21,7 +22,8 @@ from .export import (MetricsJsonlWriter, StreamingTraceWriter,
                      read_trace, trace_events, validate_trace,
                      write_flight_jsonl, write_metrics_jsonl,
                      write_prometheus, write_trace)
-from .metrics import (MetricsRegistry, TraceCounter, counter_value,
+from .metrics import (MetricsRegistry, TraceCounter,
+                      compile_seconds_counter, counter_value,
                       dropped_spans_counter, fused_fallback_counter,
                       observe_engine, observe_fault_extras,
                       observe_ledger, registry, reset_metrics)
@@ -30,20 +32,21 @@ from .recorder import (FIELDS, FlightBuffer, RecorderSpec,
                        recorder_write, rows_to_dicts, wire_constants)
 from .spans import (DEFAULT_MAX_RESIDENT_SPANS, DEFAULT_TRACK,
                     SpanEvent, Tracer, enable_tracing, instant, span,
-                    synthesize_round_spans, tracer, tracing)
+                    tracer, tracing)
 
 __all__ = [
     "DEFAULT_MAX_RESIDENT_SPANS", "DEFAULT_TRACK", "FIELDS",
     "FlightBuffer", "MetricsJsonlWriter", "MetricsRegistry",
     "RecorderSpec", "SpanEvent", "StreamingTraceWriter", "TRACE_PID",
-    "TraceCounter", "Tracer", "counter_value", "dropped_spans_counter",
+    "TraceCounter", "Tracer", "compile_seconds_counter", "counter_value",
+    "dropped_spans_counter",
     "enable_tracing", "export", "fused_fallback_counter",
     "flight_values", "instant", "observe_engine",
     "observe_fault_extras", "observe_ledger", "parse_prometheus",
     "prometheus_text", "read_trace", "recorder_init", "recorder_rows",
     "recorder_write", "registry", "reset_metrics", "rows_to_dicts",
-    "span", "synthesize_round_spans", "trace_events", "tracer",
-    "tracing", "validate_trace", "wire_constants",
+    "span", "trace_events", "tracer", "tracing", "validate_trace",
+    "wire_constants",
     "write_flight_jsonl", "write_metrics_jsonl", "write_prometheus",
     "write_trace",
 ]

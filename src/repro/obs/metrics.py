@@ -333,6 +333,17 @@ def dropped_spans_counter(reg: MetricsRegistry | None = None
         "spans evicted from a Tracer's bounded resident buffer")
 
 
+def compile_seconds_counter(reg: MetricsRegistry | None = None
+                            ) -> MetricFamily:
+    """Seconds JAX spent per compile stage (`stage` = trace, lower,
+    backend), fed by the listener tracing registers
+    (`repro.obs.spans`)."""
+    return (reg or registry()).counter(
+        "jax_compile_seconds_total",
+        "seconds JAX spent tracing, lowering and compiling or loading "
+        "programs")
+
+
 def counter_value(metric: str, reg: MetricsRegistry | None = None,
                   **labels) -> float:
     """Read one time series back (tests, bench assertions).  First

@@ -169,6 +169,6 @@ def recorder_rows(rec: FlightBuffer) -> np.ndarray:
 
 
 def rows_to_dicts(rows: np.ndarray) -> list[dict]:
-    """[{field: float}] per row — the shape `synthesize_round_spans`
-    takes as `round_args` and the JSONL sink serializes."""
+    """[{field: float}] per row — the shape the JSONL sink
+    serializes."""
     return [{f: float(v) for f, v in zip(FIELDS, row)} for row in rows]

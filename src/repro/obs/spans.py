@@ -23,17 +23,26 @@ only ever *observe* wall clock — regression-tested in
 tests/test_obs.py, where a traced `solve()` must equal the untraced one
 bit-for-bit with zero extra retraces).
 
-Spans for phases that execute *inside* one `jax.jit` program (the
-K-round scan's outer rounds, the M inner DGD steps, the U DIHGP
-Neumann exchanges) are not host-observable per round — the host sees
-one opaque device computation.  For those, `synthesize_round_spans`
-reconstructs per-round spans from what IS measured — the enclosing
-chunk's wall clock, the round count, and the per-phase gossip weights —
-and marks every such span `"synthetic": true` in its args.  The
-timeline is solver-semantic (one span per outer round, nested
-inner/DIHGP/outer-step phases) while the durations are an evenly
-divided model, never presented as measurements.  Real per-round spans
-come for free on the sharded tier, whose round loop is host-driven.
+While a tracer is enabled, every live span also opens a
+`jax.profiler.TraceAnnotation` named ``repro:<span name>`` on the
+thread that runs it (no args, so the label is stable; the args stay
+here).  A profiler trace therefore shows the program's spans on the
+device trace's clock, and an idle gap of the device can be named by
+the span the host was in.  Instants write no annotation: the profiler
+drops zero-length events.
+
+Phases that execute *inside* one `jax.jit` program (the inner DGD
+steps, DIHGP, the outer step, gossip) are not host-observable; they
+carry `jax.named_scope` names in the compiled program instead
+(`inner_dgd`, `dihgp`, `outer_step`, `gossip`), which a device trace
+attributes op by op.
+
+Enabling tracing also registers one `jax.monitoring` listener that
+adds JAX's compile-stage durations to the registry counter
+`jax_compile_seconds_total{stage}` (`trace`, `lower`, `backend`; the
+last includes fetches from the persistent compile cache).  A span
+opened with ``compile_stages=True`` annotates itself at exit with each
+stage's increase over the span (`trace_s`, `lower_s`, `backend_s`).
 
 Usage:
 
@@ -88,21 +97,70 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: Prefix of the profiler annotation each live span opens.
+ANNOTATION_PREFIX = "repro:"
+
+#: JAX compile events -> `jax_compile_seconds_total` stage label.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+_TraceAnnotation = None
+
+
+def _on_compile_event(event: str, secs: float, **_) -> None:
+    stage = COMPILE_EVENTS.get(event)
+    if stage is not None:
+        from .metrics import compile_seconds_counter
+        compile_seconds_counter().labels(stage=stage).inc(secs)
+
+
+def _install_live_hooks() -> None:
+    """Once per process, on the first enabled use: bind the profiler
+    annotation class and register the compile-duration listener."""
+    global _TraceAnnotation
+    if _TraceAnnotation is not None:
+        return
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_event)
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+
+
+def compile_seconds() -> dict[str, float]:
+    """Seconds JAX has spent in each compile stage since tracing was
+    first enabled (the `jax_compile_seconds_total` series)."""
+    from .metrics import counter_value
+    return {stage: counter_value("jax_compile_seconds_total", stage=stage)
+            for stage in COMPILE_EVENTS.values()}
+
 
 class _LiveSpan:
-    """Context manager that records one SpanEvent on exit."""
+    """Context manager that records one SpanEvent on exit and holds a
+    `repro:<name>` profiler annotation open while it runs."""
 
-    __slots__ = ("tracer", "name", "cat", "track", "args", "_t0")
+    __slots__ = ("tracer", "name", "cat", "track", "args",
+                 "compile_stages", "_t0", "_annotation", "_compile0")
 
-    def __init__(self, tracer, name, cat, track, args):
+    def __init__(self, tracer, name, cat, track, args,
+                 compile_stages=False):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.track = track
         self.args = args
+        self.compile_stages = compile_stages
         self._t0 = 0.0
+        self._annotation = None
+        self._compile0 = None
 
     def __enter__(self):
+        self._annotation = _TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
+        if self.compile_stages:
+            self._compile0 = compile_seconds()
         self._t0 = time.perf_counter()
         return self
 
@@ -112,9 +170,13 @@ class _LiveSpan:
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         tr = self.tracer
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
+        if self.compile_stages:
+            for stage, secs in compile_seconds().items():
+                self.args[f"{stage}_s"] = secs - self._compile0[stage]
         tr._record(SpanEvent(
             name=self.name, cat=self.cat,
             ts_us=(self._t0 - tr.epoch) * 1e6,
@@ -188,11 +250,16 @@ class Tracer:
             pass
 
     def span(self, name: str, cat: str = "solver",
-             track: str = DEFAULT_TRACK, **args):
-        """Context manager timing one phase; no-op when disabled."""
+             track: str = DEFAULT_TRACK, *, compile_stages: bool = False,
+             **args):
+        """Context manager timing one phase; no-op when disabled.
+        `compile_stages` adds the JAX compile seconds spent during the
+        span to its args at exit."""
         if not self.enabled:
             return _NULL_SPAN
-        return _LiveSpan(self, name, cat, track, dict(args))
+        _install_live_hooks()
+        return _LiveSpan(self, name, cat, track, dict(args),
+                         compile_stages)
 
     def instant(self, name: str, cat: str = "solver",
                 track: str = DEFAULT_TRACK, **args) -> None:
@@ -203,17 +270,6 @@ class Tracer:
             name=name, cat=cat,
             ts_us=(time.perf_counter() - self.epoch) * 1e6,
             dur_us=None, track=track, args=dict(args)))
-
-    def add_span(self, name: str, ts_us: float, dur_us: float,
-                 cat: str = "solver", track: str = DEFAULT_TRACK,
-                 **args) -> None:
-        """Record a span with explicit timing — the synthesized-span
-        entry point (callers own the honesty of the timestamps)."""
-        if not self.enabled:
-            return
-        self._record(SpanEvent(
-            name=name, cat=cat, ts_us=float(ts_us),
-            dur_us=float(dur_us), track=track, args=dict(args)))
 
     def now_us(self) -> float:
         """Current timestamp on the tracer clock (µs since epoch)."""
@@ -246,6 +302,8 @@ def tracer() -> Tracer:
 
 
 def enable_tracing(enabled: bool = True) -> Tracer:
+    if enabled:
+        _install_live_hooks()
     _TRACER.enabled = bool(enabled)
     return _TRACER
 
@@ -254,6 +312,8 @@ def enable_tracing(enabled: bool = True) -> Tracer:
 def tracing(enabled: bool = True):
     """Scoped enable/disable of the default tracer."""
     prev = _TRACER.enabled
+    if enabled:
+        _install_live_hooks()
     _TRACER.enabled = bool(enabled)
     try:
         yield _TRACER
@@ -269,51 +329,3 @@ def span(name: str, cat: str = "solver", track: str = DEFAULT_TRACK,
 def instant(name: str, cat: str = "solver", track: str = DEFAULT_TRACK,
             **args) -> None:
     _TRACER.instant(name, cat, track, **args)
-
-
-# ---------------------------------------------------------------------------
-# Synthesized solver-phase spans (in-jit rounds, reconstructed)
-# ---------------------------------------------------------------------------
-
-def synthesize_round_spans(tr: Tracer, *, t0_us: float, dur_us: float,
-                           rounds: int, phases=None,
-                           track: str = "solver",
-                           round_args: "list[dict] | None" = None,
-                           name: str = "outer_round",
-                           cat: str = "solver.round") -> int:
-    """Reconstruct per-round spans for a jitted K-round computation.
-
-    The device ran `rounds` outer rounds inside one opaque program of
-    measured wall clock `dur_us` starting at `t0_us`; this emits one
-    `name` span per round (evenly divided — a model, flagged
-    `synthetic: true`) and, when `phases` is given as (label, weight)
-    pairs, nests child spans splitting each round proportionally to the
-    weights (e.g. the M inner-DGD, U DIHGP and 1 outer-step gossip
-    exchanges).  `round_args[k]` attaches per-round scalars (flight-
-    recorder rows: outer gap, penalty, bytes) to round k's span.
-    Returns the number of events emitted."""
-    if not tr.enabled or rounds <= 0 or dur_us <= 0:
-        return 0
-    per = dur_us / rounds
-    weights = None
-    if phases:
-        total = float(sum(w for _, w in phases))
-        if total > 0:
-            weights = [(label, w / total) for label, w in phases if w > 0]
-    emitted = 0
-    for k in range(rounds):
-        ts = t0_us + k * per
-        args = {"round": k, "synthetic": True}
-        if round_args is not None and k < len(round_args):
-            args.update(round_args[k])
-        tr.add_span(name, ts, per, cat=cat, track=track, **args)
-        emitted += 1
-        if weights:
-            off = 0.0
-            for label, frac in weights:
-                tr.add_span(label, ts + off, per * frac,
-                            cat=cat + ".phase", track=track,
-                            round=k, synthetic=True)
-                off += per * frac
-                emitted += 1
-    return emitted

@@ -214,7 +214,8 @@ class AdmissionLoop(ServeEngine):
         happens here, before the job enters the queue."""
         specs = [specs] if isinstance(specs, JobSpec) else list(specs)
         ids: list[str] = []
-        with self._wake:
+        with self._wake, obs.span("submit_locked", cat="serve.admission",
+                                  track="engine", jobs=len(specs)):
             self._maybe_restore()
             self._open_telemetry()   # the submit instant must be seen
             for spec in specs:
@@ -232,7 +233,9 @@ class AdmissionLoop(ServeEngine):
                 priority = klass.priority
                 if self.quotas is not None:
                     priority = self.quotas.admit(spec.tenant, priority)
-                prob = build_problem(spec)
+                with obs.span("build_problem", cat="serve.admission",
+                              track="engine"):
+                    prob = build_problem(spec)
                 deadline = None if klass.deadline_s is None \
                     else time.monotonic() + klass.deadline_s
                 self.queue.push(QueueEntry(
@@ -272,29 +275,45 @@ class AdmissionLoop(ServeEngine):
         with self._lock:
             self._maybe_restore()
             self._open_telemetry()
-            worked = self._admit_phase()
-            inflight = obs.registry().gauge(
-                "serve_inflight_jobs",
-                "active slots in the currently running bucket")
-            for live in list(self._live.values()):
-                if not live.bucket.any_active():
-                    continue
-                inflight.set(float(sum(
-                    int(lb.bucket.active.sum())
-                    for lb in self._live.values())))
-                self._advance_bucket(live.bucket, live.T,
-                                     self._results,
-                                     self._backfill_for(live))
-                worked = True
-                self._maybe_checkpoint_loop()
-            self._reap_idle()
-            inflight.set(float(sum(
-                int(lb.bucket.active.sum())
-                for lb in self._live.values())))
-            if not worked and self._ckpt_dirty and not self.queue \
-                    and not self._live:
-                self._clear_loop_checkpoints()
-            return worked
+            if not self.queue and not any(
+                    lb.bucket.any_active() for lb in self._live.values()):
+                # nothing to do: no span, so a polling idle loop
+                # records nothing
+                self._reap_idle()
+                self._set_inflight_gauge()
+                if self._ckpt_dirty and not self._live:
+                    self._clear_loop_checkpoints()
+                return False
+            with obs.span("tick", cat="serve.admission",
+                          track="engine") as tick:
+                with obs.span("admit_phase", cat="serve.admission",
+                              track="engine"):
+                    worked = self._admit_phase()
+                slots = width = 0
+                for live in list(self._live.values()):
+                    if not live.bucket.any_active():
+                        continue
+                    self._set_inflight_gauge()
+                    slots += int(live.bucket.active.sum())
+                    width += live.bucket.width
+                    tick.annotate(active=slots, width=width)
+                    self._advance_bucket(live.bucket, live.T,
+                                         self._results,
+                                         self._backfill_for(live))
+                    worked = True
+                    self._maybe_checkpoint_loop()
+                self._reap_idle()
+                self._set_inflight_gauge()
+                if not worked and self._ckpt_dirty and not self.queue \
+                        and not self._live:
+                    self._clear_loop_checkpoints()
+                return worked
+
+    def _set_inflight_gauge(self) -> None:
+        obs.registry().gauge(
+            "serve_inflight_jobs",
+            "active slots in the currently running bucket").set(float(sum(
+                int(lb.bucket.active.sum()) for lb in self._live.values())))
 
     def pump(self) -> None:
         """Drive the loop synchronously until idle (queue empty, no
@@ -788,9 +807,19 @@ class AdmissionLoop(ServeEngine):
                         ev.set()       # unblock waiters; result() raises
                 return
             if not worked:
-                with self._wake:
-                    if not self._stopping and not self.queue:
-                        self._wake.wait(self.idle_wait_s)
+                self._wait_for_work()
+
+    def _wait_for_work(self) -> None:
+        """Poll until a job is queued or the loop stops.  One
+        `idle_wait` span covers the whole idle stretch, however many
+        polls it takes."""
+        with self._wake:
+            if self._stopping or self.queue:
+                return
+            with obs.span("idle_wait", cat="serve.admission",
+                          track="admission"):
+                while not self._stopping and not self.queue:
+                    self._wake.wait(self.idle_wait_s)
 
     def stop(self, drain: bool = True) -> None:
         """Join the scheduler thread (after draining by default) and

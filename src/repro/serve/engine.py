@@ -463,39 +463,41 @@ class ServeEngine:
         self.stats.chunks += 1
         bucket.carry = carry
 
-        ran = bucket.active.copy()   # slots that ran this chunk
-        bad = self._poisoned_slots(bucket)
-        if bad.any():
-            self._quarantine(bucket, prev_carry, bad, results,
-                             backfill)
-        # freshly backfilled slots (quarantine replacements) start
-        # at the NEXT chunk; only surviving runners earn this one
-        active = np.nonzero(ran & ~bad)[0]
-        bucket.rounds[active] += T
-        bucket.wall[active] += dt / max(len(active), 1)
-        if self.record_metrics:
-            host = jax.tree.map(np.asarray, metrics)
+        with tr.span("boundary", cat="serve.chunk", track="engine"):
+            ran = bucket.active.copy()   # slots that ran this chunk
+            bad = self._poisoned_slots(bucket)
+            if bad.any():
+                self._quarantine(bucket, prev_carry, bad, results,
+                                 backfill)
+            # freshly backfilled slots (quarantine replacements) start
+            # at the NEXT chunk; only surviving runners earn this one
+            active = np.nonzero(ran & ~bad)[0]
+            bucket.rounds[active] += T
+            bucket.wall[active] += dt / max(len(active), 1)
+            if self.record_metrics:
+                host = jax.tree.map(np.asarray, metrics)
+                for slot in active:
+                    bucket.metric_log[slot].append(
+                        {k: v[slot] for k, v in host.items()})
+            gaps = np.asarray(metrics["hypergrad_est_norm_sq"])[:, -1]
             for slot in active:
-                bucket.metric_log[slot].append(
-                    {k: v[slot] for k, v in host.items()})
-        gaps = np.asarray(metrics["hypergrad_est_norm_sq"])[:, -1]
-        for slot in active:
-            spec = bucket.slots[slot]
-            converged = spec.tol is not None \
-                and float(gaps[slot]) <= spec.tol
-            if converged or bucket.rounds[slot] >= bucket.budget[slot]:
-                rec = bucket.retire(slot, float(gaps[slot]),
-                                    converged)
-                tr.instant("retire", cat="serve.lifecycle",
-                           track="engine",
-                           job_id=rec.spec.job_id, slot=int(slot),
-                           rounds=rec.rounds,
-                           converged=rec.converged)
-                result = self._make_result(bucket, rec)
-                results[rec.spec.job_id] = result
-                self.stats.jobs_completed += 1
-                self._on_retired(rec, result)
-                backfill(bucket, slot)
+                spec = bucket.slots[slot]
+                converged = spec.tol is not None \
+                    and float(gaps[slot]) <= spec.tol
+                if converged \
+                        or bucket.rounds[slot] >= bucket.budget[slot]:
+                    rec = bucket.retire(slot, float(gaps[slot]),
+                                        converged)
+                    tr.instant("retire", cat="serve.lifecycle",
+                               track="engine",
+                               job_id=rec.spec.job_id, slot=int(slot),
+                               rounds=rec.rounds,
+                               converged=rec.converged)
+                    result = self._make_result(bucket, rec)
+                    results[rec.spec.job_id] = result
+                    self.stats.jobs_completed += 1
+                    self._on_retired(rec, result)
+                    backfill(bucket, slot)
 
     def _on_retired(self, rec, result: JobResult) -> None:
         """Retirement hook (wave mode: nothing beyond the results dict
@@ -513,7 +515,9 @@ class ServeEngine:
         while True:
             try:
                 out = fn(*args)
-                jax.block_until_ready(out)
+                with obs.span("chunk_wait", cat="serve.chunk",
+                              track="engine"):
+                    jax.block_until_ready(out)
                 return out
             except (RuntimeError, OSError) as e:
                 if attempt >= self.max_chunk_retries:

@@ -131,15 +131,6 @@ def _schedule_hp(spec: SolverSpec):
                    gamma=sched.gamma)
 
 
-def _dagm_phases(spec: SolverSpec):
-    """(label, gossip-weight) pairs for the synthesized per-round phase
-    spans: M inner DGD exchanges, U DIHGP Neumann exchanges (0 when the
-    dense-solve backend never gossips h), 1 outer (I−Ẃ)x exchange."""
-    u = 0 if spec.dihgp == "exact" else spec.U
-    return [("inner_dgd", spec.M), ("dihgp_neumann", u),
-            ("outer_step", 1)]
-
-
 def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
                           metrics_fn, recorder=None) -> SolveResult:
     from repro.core.dagm import (RoundHP, dagm_init_carry,
@@ -148,7 +139,8 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
     from repro import obs
     tr = obs.tracer()
     with tr.span("solve", cat="solver", track="solver", method="dagm",
-                 tier="reference", K=spec.K, seed=seed):
+                 tier="reference", K=spec.K, seed=seed,
+                 compile_stages=True):
         W = make_mixing_op(net, **mixing_kwargs(spec))
         with tr.span("init_carry", cat="solver", track="solver"):
             carry0 = dagm_init_carry(prob, W, spec, x0, y0, seed,
@@ -185,18 +177,20 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
                                   spec.K, metrics_fn, hp=hp, masks=masks,
                                   recorder=recorder)
 
-        t0 = tr.now_us()
-        out = run(
-            carry0, RoundHP(*(jnp.asarray(a, jnp.float32) for a in hp)),
-            masks, prob.data)
-        t_disp = tr.now_us()
-        if tr.enabled:
-            # the call above returned once tracing+compile+dispatch
-            # finished; waiting here makes the chunk span cover the
-            # device execution (a sync the result read below would
-            # force anyway — values are unchanged)
-            jax.block_until_ready(out)
-        t1 = tr.now_us()
+        # trace_compile: from the call until tracing, compiling (or
+        # loading from the persistent cache) and dispatch returned;
+        # chunk: the device execution after it (a sync the result read
+        # below would force anyway — values are unchanged)
+        with tr.span("trace_compile", cat="solver.compile",
+                     track="solver", rounds=spec.K):
+            out = run(
+                carry0, RoundHP(*(jnp.asarray(a, jnp.float32)
+                                  for a in hp)),
+                masks, prob.data)
+        with tr.span("chunk", cat="solver.chunk", track="solver",
+                     rounds=spec.K):
+            if tr.enabled:
+                jax.block_until_ready(out)
 
         flight = None
         if recorder is not None:
@@ -205,19 +199,6 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
         else:
             ((x, y), cs), metrics = out
         W.ledger.charge_states(cs.values())
-
-        if tr.enabled:
-            tr.add_span("trace_compile", t0, t_disp - t0,
-                        cat="solver.compile", track="solver",
-                        rounds=spec.K)
-            tr.add_span("chunk", t_disp, t1 - t_disp,
-                        cat="solver.chunk", track="solver",
-                        rounds=spec.K)
-            obs.synthesize_round_spans(
-                tr, t0_us=t_disp, dur_us=t1 - t_disp, rounds=spec.K,
-                phases=_dagm_phases(spec), track="solver",
-                round_args=(obs.rows_to_dicts(flight)
-                            if flight is not None else None))
 
         extras = {}
         if trace is not None:
@@ -307,11 +288,15 @@ def _solve_serve(prob, net, spec: SolverSpec, *, x0, y0, seed,
     job = JobSpec(family=_inline_family(prob), problem={},
                   config=dataclasses.replace(spec, tier="reference"),
                   graph=net, seed=seed)
+    from repro import obs
     prev_mf = engine.metrics_fn
     engine.metrics_fn = mf
     try:
-        engine.submit(job)
-        (res,) = engine.run()
+        with obs.span("solve", cat="solver", track="solver",
+                      method="dagm", tier="serve", K=spec.K, seed=seed,
+                      compile_stages=True):
+            engine.submit(job)
+            (res,) = engine.run()
     finally:
         engine.metrics_fn = prev_mf
     extras = {"rounds": res.rounds, "converged": res.converged,
@@ -393,39 +378,46 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
     tr = obs.tracer()
     # the sharded tier's round loop is host-driven, so — unlike the
     # reference/serve scans — these per-round spans are real wall-clock
-    # measurements (each round's metric read below syncs the device)
+    # measurements: round_dispatch puts the round's coefficients on the
+    # device and enqueues the step, round_sync reads its metrics back
+    # (which waits for the device)
     with tr.span("solve", cat="solver", track="solver", method="dagm",
-                 tier="sharded", K=spec.K, seed=seed):
+                 tier="sharded", K=spec.K, seed=seed,
+                 compile_stages=True):
         for k in range(spec.K):
-            hp = ShardedRoundCoeffs(*(jnp.float32(c) for c in
-                                      sharded_round_coeffs(
-                                          float(sched.alpha[k]),
-                                          float(sched.beta[k]),
-                                          spec.curvature, w.w_self)))
             with tr.span("outer_round", cat="solver.round",
                          track="solver", round=k):
-                if rec is not None:
-                    gamma = jnp.float32(sched.gamma[k])
-                    if channels is not None:
-                        x, y, m, channels, rec = step(
-                            x, y, batch, channels, hp, gamma, rec)
+                with tr.span("round_dispatch", cat="solver.round",
+                             track="solver"):
+                    hp = ShardedRoundCoeffs(*(
+                        jnp.float32(c) for c in sharded_round_coeffs(
+                            float(sched.alpha[k]), float(sched.beta[k]),
+                            spec.curvature, w.w_self)))
+                    if rec is not None:
+                        gamma = jnp.float32(sched.gamma[k])
+                        if channels is not None:
+                            x, y, m, channels, rec = step(
+                                x, y, batch, channels, hp, gamma, rec)
+                        elif pol.stochastic:
+                            key = jax.random.fold_in(
+                                jax.random.PRNGKey(seed ^ 0x5eed), k)
+                            x, y, m, rec = step(x, y, batch, key, hp,
+                                                gamma, rec)
+                        else:
+                            x, y, m, rec = step(x, y, batch, hp, gamma,
+                                                rec)
+                    elif channels is not None:
+                        x, y, m, channels = step(x, y, batch, channels,
+                                                 hp)
                     elif pol.stochastic:
                         key = jax.random.fold_in(
                             jax.random.PRNGKey(seed ^ 0x5eed), k)
-                        x, y, m, rec = step(x, y, batch, key, hp,
-                                            gamma, rec)
+                        x, y, m = step(x, y, batch, key, hp)
                     else:
-                        x, y, m, rec = step(x, y, batch, hp, gamma,
-                                            rec)
-                elif channels is not None:
-                    x, y, m, channels = step(x, y, batch, channels, hp)
-                elif pol.stochastic:
-                    key = jax.random.fold_in(
-                        jax.random.PRNGKey(seed ^ 0x5eed), k)
-                    x, y, m = step(x, y, batch, key, hp)
-                else:
-                    x, y, m = step(x, y, batch, hp)
-                rows.append(jax.tree.map(np.asarray, m))
+                        x, y, m = step(x, y, batch, hp)
+                with tr.span("round_sync", cat="solver.round",
+                             track="solver"):
+                    rows.append(jax.tree.map(np.asarray, m))
     metrics = {key: np.stack([r[key] for r in rows]) for key in rows[0]}
     local = jax.tree.map(lambda a: a[0], (x0, y0))
     ledger = sharded_comm_ledger(spec, local[0], local[1],

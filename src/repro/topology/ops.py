@@ -840,19 +840,22 @@ def mix_apply(W, y: jnp.ndarray) -> jnp.ndarray:
     """(W ⊗ I_d) y for stacked y of shape (n, d) [or (n, ...)].
 
     W may be a raw (n, n) array (dense matmul) or a MixingOp (backend
-    dispatch) — every hot-loop caller routes through here."""
-    if isinstance(W, MixingOp):
-        return W.mix(y)
-    flat = y.reshape(y.shape[0], -1)
-    out = W.astype(flat.dtype) @ flat
-    return out.reshape(y.shape)
+    dispatch) — every hot-loop caller routes through here, so each
+    gossip carries the `gossip` named scope in the compiled program."""
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.mix(y)
+        flat = y.reshape(y.shape[0], -1)
+        out = W.astype(flat.dtype) @ flat
+        return out.reshape(y.shape)
 
 
 def laplacian_apply(W, y: jnp.ndarray) -> jnp.ndarray:
     """((I - W) ⊗ I_d) y — the penalty-gradient mixing term."""
-    if isinstance(W, MixingOp):
-        return W.laplacian(y)
-    return y - mix_apply(W, y)
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.laplacian(y)
+        return y - mix_apply(W, y)
 
 
 def _neumann_update(mix, h, hvp_h, p, d_scalar, beta: float):
@@ -872,9 +875,11 @@ def fused_neumann_step(W, h, hvp_h, p, d_scalar, beta: float):
 
     MixingOp dispatches to the fused Pallas kernel on the circulant
     tier; the array/dense path composes the same algebra in XLA."""
-    if isinstance(W, MixingOp):
-        return W.neumann_step(h, hvp_h, p, d_scalar, beta)
-    return _neumann_update(mix_apply(W, h), h, hvp_h, p, d_scalar, beta)
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.neumann_step(h, hvp_h, p, d_scalar, beta)
+        return _neumann_update(mix_apply(W, h), h, hvp_h, p, d_scalar,
+                               beta)
 
 
 # ---------------------------------------------------------------------------
@@ -887,21 +892,24 @@ def fused_neumann_step(W, h, hvp_h, p, d_scalar, beta: float):
 
 def mix_apply_c(W, y: jnp.ndarray, st):
     """(W ⊗ I) y through the gossip channel -> (mixed, state)."""
-    if isinstance(W, MixingOp):
-        return W.mix_c(y, st)
-    return mix_apply(W, y), st.bump()
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.mix_c(y, st)
+        return mix_apply(W, y), st.bump()
 
 
 def laplacian_apply_c(W, y: jnp.ndarray, st):
     """((I − W) ⊗ I) y through the gossip channel -> (out, state)."""
-    if isinstance(W, MixingOp):
-        return W.laplacian_c(y, st)
-    return laplacian_apply(W, y), st.bump()
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.laplacian_c(y, st)
+        return laplacian_apply(W, y), st.bump()
 
 
 def fused_neumann_step_c(W, h, hvp_h, p, d_scalar, beta: float, st):
     """Compressed-channel twin of `fused_neumann_step`."""
-    if isinstance(W, MixingOp):
-        return W.neumann_step_c(h, hvp_h, p, d_scalar, beta, st)
-    return _neumann_update(mix_apply(W, h), h, hvp_h, p, d_scalar,
-                           beta), st.bump()
+    with jax.named_scope("gossip"):
+        if isinstance(W, MixingOp):
+            return W.neumann_step_c(h, hvp_h, p, d_scalar, beta, st)
+        return _neumann_update(mix_apply(W, h), h, hvp_h, p, d_scalar,
+                               beta), st.bump()

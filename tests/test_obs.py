@@ -84,22 +84,6 @@ def test_span_records_exception_and_reraises():
     assert "RuntimeError" in ev.args["error"]
 
 
-def test_synthesize_round_spans_weights_and_args():
-    tr = obs.Tracer(enabled=True)
-    obs.synthesize_round_spans(
-        tr, t0_us=0.0, dur_us=300.0, rounds=3,
-        phases=[("inner", 2), ("outer", 1)],
-        round_args=[{"gap": float(k)} for k in range(3)])
-    rounds = [e for e in tr.events() if e.name == "outer_round"]
-    phases = [e for e in tr.events() if e.name in ("inner", "outer")]
-    assert len(rounds) == 3 and len(phases) == 6
-    assert all(e.args["synthetic"] for e in rounds + phases)
-    assert [e.args["gap"] for e in rounds] == [0.0, 1.0, 2.0]
-    # phase children split each round's 100us by 2:1 weight
-    inner = next(e for e in phases if e.name == "inner")
-    assert inner.dur_us == pytest.approx(100.0 * 2 / 3)
-
-
 # ---------------------------------------------------------------------------
 # metrics / TraceCounter
 # ---------------------------------------------------------------------------
@@ -298,12 +282,10 @@ def test_reference_solve_bitwise_identical_with_obs_on():
     assert np.all(flight[:, obs.FIELDS.index("alive_fraction")] == 1.0)
 
     names = {e.name for e in tr.events()}
-    assert {"solve", "init_carry", "trace_compile", "chunk",
-            "outer_round"} <= names
+    assert {"solve", "init_carry", "trace_compile", "chunk"} <= names
     obs.validate_trace(obs.trace_events(tr))
-    rounds = [e for e in tr.events() if e.name == "outer_round"]
-    assert len(rounds) == spec.K
-    assert all(e.args["synthetic"] for e in rounds)
+    # the K rounds run inside one program: no host span per round
+    assert "outer_round" not in names
 
 
 def test_reference_faulted_alive_fraction_matches_host_trace():
@@ -502,3 +484,264 @@ def test_serve_prebuilt_engine_recorder_mismatch():
     with pytest.raises(ValueError, match="flight_recorder"):
         solve(prob, net, _spec(K=4, tier="serve"), serve_engine=eng,
               recorder=obs.RecorderSpec())
+
+
+# ---------------------------------------------------------------------------
+# one clock: spans as profiler annotations, compile stages per solve
+# ---------------------------------------------------------------------------
+
+class _Annotations:
+    """Stand-in for `jax.profiler.TraceAnnotation` that logs use."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kwargs):
+        log = self.log
+        log.append(("new", name, kwargs))
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+        return _Ann()
+
+
+def test_disabled_span_creates_no_annotation(monkeypatch):
+    from repro.obs import spans
+    spans._install_live_hooks()
+    fake = _Annotations()
+    monkeypatch.setattr(spans, "_TraceAnnotation", fake)
+    with obs.span("quiet", cat="t", k=1):
+        pass
+    assert fake.log == []
+    assert obs.span("quiet") is spans._NULL_SPAN
+    with obs.tracing():
+        with obs.span("work", cat="t", k=1):
+            with obs.span("inner", cat="t"):
+                pass
+    assert fake.log == [("new", "repro:work", {}), ("enter", "repro:work"),
+                        ("new", "repro:inner", {}),
+                        ("enter", "repro:inner"), ("exit", "repro:inner"),
+                        ("exit", "repro:work")]
+    # the span's args stay in obs; the annotation has none
+    (_, work) = obs.tracer().events()
+    assert work.name == "work" and work.args == {"k": 1}
+
+
+def _count_traces():
+    """A jax.monitoring listener counting jaxpr traces; returns the
+    counter list (one element, mutated)."""
+    import jax
+    n = [0]
+
+    def on(event, secs, **_):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            n[0] += 1
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return n
+
+
+@pytest.mark.parametrize("tier", ["reference", "serve"])
+def test_solve_span_counts_compile_stages_bitwise_no_retrace(tier):
+    prob, net = _problem()
+    spec = _spec(K=8, tier=tier)
+    base = solve(prob, net, spec)          # warms every helper program
+    n = _count_traces()
+    solve(prob, net, spec)
+    off = n[0]
+    n[0] = 0
+    with obs.tracing() as tr:
+        res = solve(prob, net, spec)
+    assert n[0] == off                      # tracing adds no retrace
+    assert np.array_equal(np.asarray(base.x), np.asarray(res.x))
+    assert np.array_equal(np.asarray(base.y), np.asarray(res.y))
+    (sp,) = [e for e in tr.events() if e.name == "solve"]
+    assert sp.args["tier"] == tier
+    if tier == "reference":
+        # the bare solve() builds a fresh program per call: every call
+        # traces, lowers and compiles (or loads) it
+        assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
+            and sp.args["backend_s"] > 0
+        assert obs.counter_value("jax_compile_seconds_total",
+                                 stage="trace") >= sp.args["trace_s"]
+    names = {e.name for e in tr.events()}
+    if tier == "serve":
+        assert {"chunk", "chunk_wait", "boundary"} <= names
+    obs.validate_trace(obs.trace_events(tr))
+
+
+def test_serve_solve_span_first_call_compiles():
+    """A fresh engine's first call traces, lowers and compiles its
+    bucket program inside the solve span."""
+    prob, net = _problem()
+    with obs.tracing() as tr:
+        solve(prob, net, _spec(K=8, tier="serve"))
+    (sp,) = [e for e in tr.events() if e.name == "solve"]
+    assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
+        and sp.args["backend_s"] > 0
+
+
+def test_reference_spans_nest_on_the_solver_track():
+    prob, net = _problem()
+    with obs.tracing() as tr:
+        solve(prob, net, _spec(K=4))
+    ev = {e.name: e for e in tr.events()}
+    solve_ev = ev["solve"]
+    for name in ("init_carry", "trace_compile", "chunk"):
+        e = ev[name]
+        assert e.track == "solver"
+        assert solve_ev.ts_us <= e.ts_us
+        assert e.ts_us + e.dur_us <= solve_ev.ts_us + solve_ev.dur_us + 1e-6
+    # trace_compile ends where the device wait (chunk) begins
+    assert ev["trace_compile"].ts_us + ev["trace_compile"].dur_us \
+        <= ev["chunk"].ts_us + 1e-6
+
+
+def test_sharded_solve_round_spans_bitwise():
+    """The host-driven round loop: per round one outer_round span with
+    round_dispatch then round_sync inside it (one-device mesh)."""
+    import jax
+    from jax.sharding import Mesh
+    from repro.core import quadratic_bilevel
+    from repro.solve import sharded_spec
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    prob = quadratic_bilevel(1, 3, 4, seed=0)
+    spec = sharded_spec(alpha=0.05, beta=0.1, M=2, U=2, curvature=5.0,
+                        K=3)
+    base = solve(prob, None, spec, mesh=mesh)
+    with obs.tracing() as tr:
+        res = solve(prob, None, spec, mesh=mesh)
+    assert np.array_equal(np.asarray(base.x), np.asarray(res.x))
+    assert np.array_equal(np.asarray(base.y), np.asarray(res.y))
+    events = tr.events()
+    rounds = [e for e in events if e.name == "outer_round"]
+    assert [e.args["round"] for e in rounds] == [0, 1, 2]
+    for name in ("round_dispatch", "round_sync"):
+        inner = [e for e in events if e.name == name]
+        assert len(inner) == 3
+        for r, e in zip(rounds, inner):
+            assert r.ts_us <= e.ts_us
+            assert e.ts_us + e.dur_us <= r.ts_us + r.dur_us + 1e-6
+    (sp,) = [e for e in events if e.name == "solve"]
+    # make_sharded_dagm builds a fresh step per call
+    assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
+        and sp.args["backend_s"] > 0
+    obs.validate_trace(obs.trace_events(tr))
+
+
+def test_admission_loop_host_spans_and_lifecycle_instants():
+    import dataclasses
+    from repro.serve import JobSpec
+    from repro.serve.admission import AdmissionLoop
+    cfg = dataclasses.replace(_spec(K=8), tier="reference")
+    specs = [JobSpec("quadratic", {"n": 6, "d1": 4, "d2": 8, "seed": s},
+                     cfg, seed=s, job_id=f"j{s}", tenant="t0")
+             for s in range(3)]
+    loop = AdmissionLoop(chunk_rounds=4, bucket_width=2, telemetry=False)
+    with obs.tracing() as tr:
+        loop.submit(specs)
+        loop.pump()
+    events = tr.events()
+    names = {e.name for e in events}
+    assert {"tick", "admit_phase", "chunk", "chunk_wait", "boundary",
+            "submit_locked", "build_problem"} <= names
+    submits = [e for e in events if e.name == "submit"]
+    assert [e.args for e in submits] == [
+        {"job_id": f"j{s}", "klass": "standard", "tenant": "t0"}
+        for s in range(3)]
+    admits = [e for e in events if e.name == "admit"]
+    assert {e.args["job_id"] for e in admits} == {"j0", "j1", "j2"}
+    assert all(set(e.args) == {"job_id", "slot", "rounds", "klass"}
+               for e in admits)
+    assert all(e.dur_us is None for e in submits + admits)
+    # no new span carries a job id or takes an instant's name
+    spans = [e for e in events if e.dur_us is not None]
+    assert not any("job_id" in e.args for e in spans)
+    assert not {"submit", "admit"} & {e.name for e in spans}
+    (locked,) = [e for e in spans if e.name == "submit_locked"]
+    assert locked.args == {"jobs": 3}
+    built = [e for e in spans if e.name == "build_problem"]
+    assert len(built) == 3 and all(
+        locked.ts_us <= e.ts_us and e.ts_us + e.dur_us
+        <= locked.ts_us + locked.dur_us + 1e-6 for e in built)
+    advanced = [e for e in spans if e.name == "tick" and "width" in e.args]
+    # 3 jobs of 2 chunks through 2 slots: j0, j1, then j2 alone
+    assert [(e.args["active"], e.args["width"]) for e in advanced] == [
+        (2, 2), (2, 2), (1, 2), (1, 2)]
+    waits = [e for e in spans if e.name == "chunk_wait"]
+    assert len(waits) == 4 and all(
+        any(t.ts_us <= w.ts_us and w.ts_us + w.dur_us
+            <= t.ts_us + t.dur_us + 1e-6 for t in advanced)
+        for w in waits)
+    obs.validate_trace(obs.trace_events(tr))
+
+
+def test_admission_loop_thread_spans_its_idle_wait():
+    """The scheduler thread's wait for work is one span per idle
+    stretch, however many polls it takes, so a device idle gap while
+    the loop has nothing to do is named; an idle tick records
+    nothing."""
+    import dataclasses
+    import time
+    from repro.serve import JobSpec
+    from repro.serve.admission import AdmissionLoop
+    cfg = dataclasses.replace(_spec(K=4), tier="reference")
+    spec = JobSpec("quadratic", {"n": 6, "d1": 4, "d2": 8, "seed": 0},
+                   cfg, seed=0, job_id="j0")
+    with obs.tracing() as tr:
+        loop = AdmissionLoop(chunk_rounds=4, bucket_width=2,
+                             telemetry=False, idle_wait_s=0.005)
+        with loop:
+            time.sleep(0.05)        # about 10 polls with nothing queued
+            loop.submit(spec)
+            loop.result("j0")
+            time.sleep(0.05)
+    events = tr.events()
+    waits = [e for e in events if e.name == "idle_wait"]
+    # before the submit, and after the job until stop()
+    assert len(waits) == 2 and all(e.track == "admission" for e in waits)
+    assert waits[0].dur_us >= 2e4           # many 5 ms polls, one span
+    assert {"tick", "chunk_wait", "boundary"} <= {e.name for e in events}
+    # every tick admitted or advanced: the idle polls opened none
+    ticks = [e for e in events if e.name == "tick"]
+    assert len(ticks) == 1 and ticks[0].args == {"active": 1, "width": 2}
+    obs.validate_trace(obs.trace_events(tr))
+
+
+def test_round_programs_name_the_four_phases():
+    """The lowered DAGM round (reference and sharded) carries the
+    inner_dgd / dihgp / outer_step / gossip scopes."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    from repro.core.dagm import dagm_init_carry, dagm_outer_step_c
+    from repro.distributed.collectives import RingWeights
+    from repro.distributed.dagm_sharded import dagm_local_round
+    from repro.solve import sharded_spec
+    prob, net = _problem()
+    spec = _spec(K=2)
+    W = make_mixing_op(net, **mixing_kwargs(spec))
+    (x, y), cs = dagm_init_carry(prob, W, spec)
+    text = jax.jit(lambda x, y, cs: dagm_outer_step_c(
+        prob, W, spec, x, y, cs)).lower(x, y, cs).as_text(debug_info=True)
+    for scope in ("inner_dgd", "dihgp", "outer_step", "gossip"):
+        assert f"/{scope}/" in text, scope
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    cfg = sharded_spec(alpha=0.05, beta=0.1, M=2, U=2, curvature=5.0)
+    w = RingWeights.metropolis_ring(1)
+    g = lambda x, y, b: jnp.sum((y - x) ** 2)
+    f = lambda x, y, b: jnp.sum(y ** 2)
+
+    def local(x, y):
+        x1, y1, _ = dagm_local_round(g, f, cfg, w, x[0], y[0], None)
+        return x1[None], y1[None]
+    step = jax.jit(shard_map(local, mesh=mesh, in_specs=(P("data"),) * 2,
+                             out_specs=(P("data"),) * 2, check_vma=False))
+    text = step.lower(jnp.zeros((1, 3)), jnp.ones((1, 3))).as_text(
+        debug_info=True)
+    for scope in ("inner_dgd", "dihgp", "outer_step", "gossip"):
+        assert f"/{scope}/" in text, scope

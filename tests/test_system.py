@@ -110,3 +110,61 @@ def test_compile_cache_placement(monkeypatch):
         assert compile_cache.enable_compile_cache() == path   # stable
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+
+CACHE_KEY_SCRIPT = r'''
+import os, sys
+os.environ["JAX_COMPILATION_CACHE_DIR"] = sys.argv[1]
+sys.path.insert(0, sys.argv[2])
+import jax
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+from repro.compile_cache import enable_compile_cache
+enable_compile_cache()
+SRC = """
+import jax, jax.numpy as jnp
+def run(scope):
+    @jax.jit
+    def f(a):
+        def body(i, c):
+            with jax.named_scope(scope):
+                return jnp.tanh(c @ c)
+        return jax.lax.fori_loop(0, 2, body, a).sum()
+    a = jnp.ones((8, 8))
+    f(a).block_until_ready()
+    return f.lower(a).compile().as_text()
+"""
+def entries():
+    return len([f for f in os.listdir(sys.argv[1]) if f.startswith("jit_f")])
+def deeper(ns):
+    return ns["run"]("gossip")
+# the same source at two paths, called from two depths: one entry
+for path in ("/a/checkout/k.py", "/b/elsewhere/deeper/k.py"):
+    ns = {}
+    exec(compile(SRC, path, "exec"), ns)
+    ns["run"]("gossip") if path.startswith("/a") else deeper(ns)
+print("SAME", entries())
+hlo = ns["run"]("dihgp")    # another scope name: another entry
+print("SCOPED", entries())
+# the compiled ops keep their whole scope path (a device trace reads it)
+print("OPNAME", 'op_name="jit(f)/while/body/closed_call/dihgp/' in hlo)
+'''
+
+
+def test_compile_cache_key_follows_scopes_not_paths(tmp_path):
+    """The persistent cache keys on the ops' named scopes (an executable
+    keeps the metadata it was compiled with) but not on where the
+    checkout lies or from where the program is called."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", CACHE_KEY_SCRIPT, str(tmp_path),
+         os.path.abspath(src)],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SAME 1" in out.stdout and "SCOPED 2" in out.stdout, out.stdout
+    assert "OPNAME True" in out.stdout, out.stdout
