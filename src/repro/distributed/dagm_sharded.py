@@ -55,7 +55,9 @@ class ShardedRoundCoeffs(NamedTuple):
 def sharded_round_coeffs(alpha: float, beta: float, curvature: float,
                          w_self: float) -> ShardedRoundCoeffs:
     """Host-side (float64) coefficient math matching the legacy config
-    path, rounded to f32 once at the use sites' precision."""
+    path, rounded to f32 once at the use sites' precision.  `alpha` and
+    `beta` may be float64 arrays of per-round values: each field then
+    holds one f32 value per round, bit-equal to the scalar calls."""
     d = beta * curvature + 2.0 * (1.0 - w_self)
     return ShardedRoundCoeffs(
         neg_beta=np.float32(-beta), beta=np.float32(beta),
@@ -476,11 +478,12 @@ def make_sharded_dagm(g_fn: Callable, f_fn: Callable,
                         out_specs=(xs, ys, P()), check_vma=False, **kw)
     if not jit_step:
         return step, w
-    # jit through the shared obs trace counter: the sharded tier's
-    # host-driven round loop calls this step K times, so a retrace
-    # (anything but jit_traces_total{name="sharded_dagm_step"} == 1
-    # per program) would multiply compile cost K-fold — the same
-    # zero-retrace telemetry the serve engine and benches publish
+    # jit through the shared obs trace counter: a caller driving this
+    # step round by round calls it K times, so a retrace (anything but
+    # jit_traces_total{name="sharded_dagm_step"} == 1 per program)
+    # would multiply compile cost K-fold — the same zero-retrace
+    # telemetry the serve engine and benches publish.  `repro.solve`
+    # takes the step unjitted and scans it (`sharded_dagm_run`).
     from repro.obs import TraceCounter
     return TraceCounter("sharded_dagm_step").wrap(step), w
 

@@ -21,9 +21,9 @@ One call signature dispatches every method × tier combination:
   one-job bucket (same chunk machinery, width-padded).  Because solo
   and serve now share the traced-operand program, the trajectories are
   bit-exact across tiers.
-* ``tier="sharded"`` — the `distributed` shard_map program over a
-  caller-supplied mesh; per-round coefficient operands feed the same
-  schedules into one compiled step.
+* ``tier="sharded"`` — the `distributed` shard_map step over a
+  caller-supplied mesh, scanned over all K rounds in one jitted
+  program; the schedules enter it as (K,) coefficient operands.
 
 Every tier returns a `SolveResult` (final iterates, per-round metric
 trajectory, byte-accurate CommLedger, final gossip channel states).
@@ -350,7 +350,8 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
         batch = prob.data
 
     step, w = make_sharded_dagm(g_fn, f_fn, spec, mesh,
-                                schedule_hp=True, recorder=recorder)
+                                schedule_hp=True, jit_step=False,
+                                recorder=recorder)
     ax = spec.sharded.axis
     ax_names = ax if isinstance(ax, tuple) else (ax,)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -371,54 +372,59 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
     pol = _sharded_policy(spec)
     channels = open_sharded_channels(spec, x0, y0, seed) \
         if spec.comm.persist_ef else None
-    x, y = x0, y0
-    rows = []
     from repro import obs
     rec = obs.recorder_init(recorder) if recorder is not None else None
+    # the round's scan inputs: the coefficients (float64 host math,
+    # rounded to f32 once), γ for the flight rows, and the round index
+    # that the stochastic policies fold into their key
+    hp = ShardedRoundCoeffs(*(np.asarray(c, np.float32)
+                              for c in sharded_round_coeffs(
+                                  sched.alpha.astype(np.float64),
+                                  sched.beta.astype(np.float64),
+                                  spec.curvature, w.w_self)))
+    gamma = sched.gamma if rec is not None else None
+    base = jax.random.PRNGKey(seed ^ 0x5eed) \
+        if pol.stochastic and channels is None else None
+
+    # all K rounds in one program: the step scanned on the device, the
+    # metrics stacked and read once.  The batch is an argument — closed
+    # over, it would be compiled in as constants.
+    def run(x, y, channels, rec, batch, base, hp, gamma):
+        def body(carry, xs):
+            x, y, cs, rec = carry
+            k, hp, gamma = xs
+            if cs is not None:
+                args = (cs,)
+            elif base is not None:
+                args = (jax.random.fold_in(base, k),)
+            else:
+                args = ()
+            tail = (gamma, rec) if rec is not None else ()
+            out = step(x, y, batch, *args, hp, *tail)
+            x, y, m = out[:3]
+            if cs is not None:
+                cs = out[3]
+            if rec is not None:
+                rec = out[-1]
+            return (x, y, cs, rec), m
+
+        ks = jnp.arange(spec.K, dtype=jnp.int32)
+        return jax.lax.scan(body, (x, y, channels, rec), (ks, hp, gamma))
+
+    run = obs.TraceCounter("sharded_dagm_run").wrap(run)
     tr = obs.tracer()
-    # the sharded tier's round loop is host-driven, so — unlike the
-    # reference/serve scans — these per-round spans are real wall-clock
-    # measurements: round_dispatch puts the round's coefficients on the
-    # device and enqueues the step, round_sync reads its metrics back
-    # (which waits for the device)
     with tr.span("solve", cat="solver", track="solver", method="dagm",
                  tier="sharded", K=spec.K, seed=seed,
                  compile_stages=True):
-        for k in range(spec.K):
-            with tr.span("outer_round", cat="solver.round",
-                         track="solver", round=k):
-                with tr.span("round_dispatch", cat="solver.round",
-                             track="solver"):
-                    hp = ShardedRoundCoeffs(*(
-                        jnp.float32(c) for c in sharded_round_coeffs(
-                            float(sched.alpha[k]), float(sched.beta[k]),
-                            spec.curvature, w.w_self)))
-                    if rec is not None:
-                        gamma = jnp.float32(sched.gamma[k])
-                        if channels is not None:
-                            x, y, m, channels, rec = step(
-                                x, y, batch, channels, hp, gamma, rec)
-                        elif pol.stochastic:
-                            key = jax.random.fold_in(
-                                jax.random.PRNGKey(seed ^ 0x5eed), k)
-                            x, y, m, rec = step(x, y, batch, key, hp,
-                                                gamma, rec)
-                        else:
-                            x, y, m, rec = step(x, y, batch, hp, gamma,
-                                                rec)
-                    elif channels is not None:
-                        x, y, m, channels = step(x, y, batch, channels,
-                                                 hp)
-                    elif pol.stochastic:
-                        key = jax.random.fold_in(
-                            jax.random.PRNGKey(seed ^ 0x5eed), k)
-                        x, y, m = step(x, y, batch, key, hp)
-                    else:
-                        x, y, m = step(x, y, batch, hp)
-                with tr.span("round_sync", cat="solver.round",
-                             track="solver"):
-                    rows.append(jax.tree.map(np.asarray, m))
-    metrics = {key: np.stack([r[key] for r in rows]) for key in rows[0]}
+        with tr.span("trace_compile", cat="solver.compile",
+                     track="solver", rounds=spec.K):
+            out = run(x0, y0, channels, rec, batch, base, hp, gamma)
+        with tr.span("chunk", cat="solver.chunk", track="solver",
+                     rounds=spec.K):
+            if tr.enabled:
+                jax.block_until_ready(out)
+        (x, y, channels, rec), ms = out
+        metrics = jax.tree.map(np.asarray, ms)
     local = jax.tree.map(lambda a: a[0], (x0, y0))
     ledger = sharded_comm_ledger(spec, local[0], local[1],
                                  rounds=spec.K)

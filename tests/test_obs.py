@@ -601,8 +601,9 @@ def test_reference_spans_nest_on_the_solver_track():
 
 
 def test_sharded_solve_round_spans_bitwise():
-    """The host-driven round loop: per round one outer_round span with
-    round_dispatch then round_sync inside it (one-device mesh)."""
+    """The sharded tier scans its rounds on the device: one
+    trace_compile then one chunk span inside solve, each with
+    rounds=K, and no per-round host span (one-device mesh)."""
     import jax
     from jax.sharding import Mesh
     from repro.core import quadratic_bilevel
@@ -617,15 +618,16 @@ def test_sharded_solve_round_spans_bitwise():
     assert np.array_equal(np.asarray(base.x), np.asarray(res.x))
     assert np.array_equal(np.asarray(base.y), np.asarray(res.y))
     events = tr.events()
-    rounds = [e for e in events if e.name == "outer_round"]
-    assert [e.args["round"] for e in rounds] == [0, 1, 2]
-    for name in ("round_dispatch", "round_sync"):
-        inner = [e for e in events if e.name == name]
-        assert len(inner) == 3
-        for r, e in zip(rounds, inner):
-            assert r.ts_us <= e.ts_us
-            assert e.ts_us + e.dur_us <= r.ts_us + r.dur_us + 1e-6
+    names = [e.name for e in events]
+    assert not {"outer_round", "round_dispatch", "round_sync"} & set(names)
     (sp,) = [e for e in events if e.name == "solve"]
+    (tc,) = [e for e in events if e.name == "trace_compile"]
+    (ch,) = [e for e in events if e.name == "chunk"]
+    assert tc.args["rounds"] == ch.args["rounds"] == 3
+    assert tc.ts_us + tc.dur_us <= ch.ts_us + 1e-6
+    for e in (tc, ch):
+        assert sp.ts_us <= e.ts_us
+        assert e.ts_us + e.dur_us <= sp.ts_us + sp.dur_us + 1e-6
     # make_sharded_dagm builds a fresh step per call
     assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
         and sp.args["backend_s"] > 0

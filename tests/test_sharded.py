@@ -426,6 +426,104 @@ def test_solve_sharded_tier(tmp_path):
     assert vals["LEDGER_SENDS"] == 16.0    # (M + U + 1) per round
 
 
+SCRIPT_SOLVE_COMM = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import sys
+sys.path.insert(0, {src!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
+
+from repro import obs
+from repro.core import quadratic_bilevel
+from repro.distributed.dagm_sharded import (ShardedRoundCoeffs,
+                                            make_sharded_dagm,
+                                            open_sharded_channels,
+                                            sharded_round_coeffs)
+from repro.optim import inverse_sqrt_schedule
+from repro.solve import sharded_spec, solve
+
+n, K, seed = 8, 12, 3
+mesh = Mesh(np.array(jax.devices()).reshape(n), ("data",))
+prob = quadratic_bilevel(n, 3, 4, seed=0)
+curv = float(max(np.linalg.eigvalsh(np.asarray(prob.data["A"][i])).max()
+                 for i in range(n)))
+for name, persist in (("persist", True), ("stoch", False)):
+    spec = sharded_spec(alpha=inverse_sqrt_schedule(0.05), beta=0.1, M=4,
+                        U=3, curvature=curv, comm="int8+ef",
+                        persist_ef=persist, K=K)
+    t0 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
+    res = solve(prob, None, spec, mesh=mesh, seed=seed)
+    t1 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
+    solve(prob, None, spec, mesh=mesh, seed=seed)
+    t2 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
+    print("RUN_TRACES_" + name, int(t1 - t0 == 1 and t2 - t1 == 1))
+    # the per-round step driven by hand, with the same keys, channels
+    # and coefficients
+    step, w = make_sharded_dagm(lambda x, y, b: prob.g(x, y, b),
+                                lambda x, y, b: prob.f(x, y, b), spec,
+                                mesh, schedule_hp=True)
+    sched = spec.schedule.materialize(K)
+    x = jnp.zeros((n, 3), jnp.float32)
+    y = 0.01 * jax.random.normal(jax.random.PRNGKey(seed), (n, 4),
+                                 jnp.float32)
+    cs = open_sharded_channels(spec, x, y, seed) if persist else None
+    rows = []
+    for k in range(K):
+        hp = ShardedRoundCoeffs(*(jnp.float32(c) for c in
+                                  sharded_round_coeffs(
+                                      float(sched.alpha[k]),
+                                      float(sched.beta[k]), curv,
+                                      w.w_self)))
+        if persist:
+            x, y, m, cs = step(x, y, prob.data, cs, hp)
+        else:
+            key = jax.random.fold_in(jax.random.PRNGKey(seed ^ 0x5eed), k)
+            x, y, m = step(x, y, prob.data, key, hp)
+        rows.append(jax.tree.map(np.asarray, m))
+    same = (np.array_equal(np.asarray(res.x), np.asarray(x))
+            and np.array_equal(np.asarray(res.y), np.asarray(y))
+            and all(np.array_equal(res.metrics[key],
+                                   np.stack([r[key] for r in rows]))
+                    for key in rows[0]))
+    if persist:
+        same = same and all(
+            np.array_equal(np.asarray(a), np.asarray(b)) for a, b in
+            zip(jax.tree.leaves(res.channels), jax.tree.leaves(cs)))
+    print("SOLVE_BITEXACT_" + name, int(same))
+    rres = solve(prob, None, spec, mesh=mesh, seed=seed,
+                 recorder=obs.RecorderSpec(capacity=16))
+    print("RECORDED_BITSAME_" + name, int(
+        np.array_equal(np.asarray(rres.x), np.asarray(res.x))
+        and np.array_equal(np.asarray(rres.y), np.asarray(res.y))
+        and rres.extras["flight"].shape[0] == K))
+"""
+
+
+def test_solve_sharded_compressed_scan_matches_step_loop(tmp_path):
+    """`solve(tier="sharded")` scans the per-round step on the device:
+    a persist_ef int8+ef solve and a stochastic int8+ef solve (decaying
+    alpha) equal the hand-driven per-round step loop with the same
+    channels and keys (`fold_in(PRNGKey(seed ^ 0x5eed), k)`) bitwise,
+    with and without the flight recorder, and each solve() traces its
+    run program exactly once (`sharded_dagm_run`)."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    script = SCRIPT_SOLVE_COMM.format(src=os.path.abspath(src))
+    out = subprocess.run([sys.executable, "-c", script],
+                         capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    vals = {}
+    for line in out.stdout.splitlines():
+        parts = line.split()
+        if len(parts) == 2:
+            vals[parts[0]] = float(parts[1])
+    for name in ("persist", "stoch"):
+        assert vals[f"RUN_TRACES_{name}"] == 1
+        assert vals[f"SOLVE_BITEXACT_{name}"] == 1
+        assert vals[f"RECORDED_BITSAME_{name}"] == 1
+
+
 SCRIPT_FLIGHT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -448,10 +546,10 @@ spec = sharded_spec(alpha=0.05, beta=0.1, M=10, U=5, curvature=curv, K=K)
 
 # --- 1. recorder= is bitwise-inert and adds zero retraces ---
 base = solve(prob, None, spec, mesh=mesh, seed=0)
-t0 = obs.counter_value("jit_traces_total", name="sharded_dagm_step")
+t0 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
 res = solve(prob, None, spec, mesh=mesh, seed=0,
             recorder=obs.RecorderSpec(capacity=32))
-t1 = obs.counter_value("jit_traces_total", name="sharded_dagm_step")
+t1 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
 print("TRACES_DELTA", t1 - t0)
 print("BITSAME", int(np.array_equal(np.asarray(base.x), np.asarray(res.x))
                      and np.array_equal(np.asarray(base.y),
@@ -510,7 +608,7 @@ def test_sharded_flight_recorder(tmp_path):
         parts = line.split()
         if len(parts) == 2:
             vals[parts[0]] = float(parts[1])
-    assert vals["TRACES_DELTA"] == 1.0   # one compile for the recorded step
+    assert vals["TRACES_DELTA"] == 1.0   # one trace of the recorded run
     assert vals["BITSAME"] == 1
     assert vals["METRIC_KEYS_SAME"] == 1
     assert vals["ROWS"] == 12 and vals["COLS"] == 5
