@@ -26,7 +26,8 @@ from .metrics import (MetricsRegistry, TraceCounter,
                       compile_seconds_counter, counter_value,
                       dropped_spans_counter, fused_fallback_counter,
                       observe_engine, observe_fault_extras,
-                      observe_ledger, registry, reset_metrics)
+                      observe_ledger, program_cache_counter, registry,
+                      reset_metrics)
 from .recorder import (FIELDS, FlightBuffer, RecorderSpec,
                        flight_values, recorder_init, recorder_rows,
                        recorder_write, rows_to_dicts, wire_constants)
@@ -43,7 +44,8 @@ __all__ = [
     "enable_tracing", "export", "fused_fallback_counter",
     "flight_values", "instant", "observe_engine",
     "observe_fault_extras", "observe_ledger", "parse_prometheus",
-    "prometheus_text", "read_trace", "recorder_init", "recorder_rows",
+    "program_cache_counter", "prometheus_text", "read_trace",
+    "recorder_init", "recorder_rows",
     "recorder_write", "registry", "reset_metrics", "rows_to_dicts",
     "span", "trace_events", "tracer", "tracing", "validate_trace",
     "wire_constants",

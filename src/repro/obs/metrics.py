@@ -344,6 +344,16 @@ def compile_seconds_counter(reg: MetricsRegistry | None = None
         "programs")
 
 
+def program_cache_counter(reg: MetricsRegistry | None = None
+                          ) -> MetricFamily:
+    """The counter `repro.solve` ticks on every lookup of its
+    compiled-program cache (`tier` = reference, sharded; `outcome` =
+    hit, miss)."""
+    return (reg or registry()).counter(
+        "solve_program_cache_total",
+        "solve() compiled-program cache lookups by tier and outcome")
+
+
 def counter_value(metric: str, reg: MetricsRegistry | None = None,
                   **labels) -> float:
     """Read one time series back (tests, bench assertions).  First

@@ -198,10 +198,7 @@ def _signature(spec: JobSpec, prob: BilevelProblem,
         # but different W must NOT share a bucket — the bucket runs on
         # the first job's graph, which would silently solve the others
         # on the wrong topology
-        import hashlib
-        digest = hashlib.sha1(
-            np.ascontiguousarray(spec.graph.W).tobytes()).hexdigest()
-        graph = ("net", spec.graph.name, spec.graph.n, digest)
+        graph = spec.graph.content_key()
     else:
         graph = (spec.graph,) + tuple(sorted(spec.graph_kwargs.items()))
     return (spec.family, prob.n, prob.d1, prob.d2, leaf_shapes, graph,

@@ -11,12 +11,7 @@ One call signature dispatches every method × tier combination:
 * ``tier="reference"`` — one jitted K-round scan (methods "dagm",
   "dgbo", "dgtbo", "ma_dbo", "fednest").  Hyper-parameter schedules
   enter the compiled program as traced (K,) operands, so the program
-  itself is schedule-agnostic; callers that hold a compiled runner
-  (the serve engine's chunk cache, or your own jit around
-  `dagm_run_chunk`) sweep α/β/γ with zero retraces.  A bare `solve()`
-  call builds a fresh closure per invocation and does not cache
-  compiles across calls — route sweeps through ``tier="serve"`` (one
-  engine, one compile per bucket program).
+  itself is schedule-agnostic.
 * ``tier="serve"``   — the run rides the `repro.serve` engine as a
   one-job bucket (same chunk machinery, width-padded).  Because solo
   and serve now share the traced-operand program, the trajectories are
@@ -25,13 +20,30 @@ One call signature dispatches every method × tier combination:
   caller-supplied mesh, scanned over all K rounds in one jitted
   program; the schedules enter it as (K,) coefficient operands.
 
+The DAGM programs of the reference and sharded tiers are kept across
+calls in a bounded LRU (`_ProgramCache`, `PROGRAM_CACHE_SIZE` entries
+per tier).  The key holds everything the trace bakes in as a constant:
+the problem's structure without its data (name, sizes, f, g and the
+analytic callables by identity), the network by content
+(`Network.content_key`) or the mesh, the spec with its α/β/γ and fault
+schedules set aside, `metrics_fn` by identity, the recorder, the Pallas
+switch, and the shapes and dtypes of the operands.  The schedules, the
+fault masks, the problem data and the start (x0, y0, channel states)
+are operands, so a repeat call with another seed, start, schedule or
+dataset of the same shapes traces, lowers and loads nothing and runs
+the very same executable.  A new problem, network or mesh object is a
+new key.  The cached programs close over the problem with
+``data=None``: the cache never keeps a dataset alive.
+
 Every tier returns a `SolveResult` (final iterates, per-round metric
 trajectory, byte-accurate CommLedger, final gossip channel states).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Any, Callable
+import threading
+from typing import Any, Callable, NamedTuple
 
 import numpy as np
 import jax
@@ -121,6 +133,73 @@ def solve(problem, network, spec, *, x0=None, y0=None, seed: int = 0,
 
 
 # ---------------------------------------------------------------------------
+# the compiled-program cache (reference and sharded tiers)
+# ---------------------------------------------------------------------------
+
+#: Entries kept per tier; the least recently used is dropped first.
+PROGRAM_CACHE_SIZE = 64
+
+
+class _ProgramCache:
+    """Bounded LRU of jitted runners, one per tier (see the module
+    docstring for what the keys hold).  Every lookup ticks
+    `solve_program_cache_total{tier, outcome}`."""
+
+    def __init__(self, tier: str, size: int = PROGRAM_CACHE_SIZE):
+        self.tier = tier
+        self.size = size
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self._lock = threading.Lock()
+
+    def get(self, key, build: Callable):
+        """(entry, "hit" | "miss"): the entry cached under `key`, else
+        `build()`'s, which is cached."""
+        from repro import obs
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is not None:
+                self._entries.move_to_end(key)
+        outcome = "miss" if entry is None else "hit"
+        if entry is None:
+            entry = build()
+            with self._lock:
+                self._entries[key] = entry
+                while len(self._entries) > self.size:
+                    self._entries.popitem(last=False)
+        obs.program_cache_counter().labels(
+            tier=self.tier, outcome=outcome).inc()
+        return entry, outcome
+
+    def entries(self) -> list:
+        with self._lock:
+            return list(self._entries.values())
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+
+def _spec_key(spec: SolverSpec) -> SolverSpec:
+    """The spec as the trace reads it: the α/β/γ schedule and the fault
+    schedule enter as operands (`RoundHP` / `ShardedRoundCoeffs`, the
+    mask array), so both are set aside."""
+    return dataclasses.replace(spec, schedule=None, faults=None)
+
+
+def _operand_key(operands) -> tuple:
+    """Tree structure and abstract values (shape, dtype, weak type) of
+    a call's operands: what jit itself retraces on."""
+    leaves, treedef = jax.tree.flatten(operands)
+    return treedef, tuple(jax.typeof(a) for a in leaves)
+
+
+def _pallas_key() -> tuple:
+    # MixingOp reads the Pallas switch while tracing
+    from repro.kernels.ops import pallas_enabled
+    return pallas_enabled()
+
+
+# ---------------------------------------------------------------------------
 # reference tier
 # ---------------------------------------------------------------------------
 
@@ -131,10 +210,51 @@ def _schedule_hp(spec: SolverSpec):
                    gamma=sched.gamma)
 
 
+class _ReferenceProgram(NamedTuple):
+    run: Callable     # jitted (carry, hp, masks, data) -> (carry, metrics)
+    problem: Any      # the problem it closes over, with data=None
+
+
+_REFERENCE_PROGRAMS = _ProgramCache("reference")
+
+
+def _reference_key(prob, net, spec, metrics_fn, recorder, operands):
+    structure = (prob.name, prob.n, prob.d1, prob.d2, prob.f, prob.g,
+                 float(prob.mu_g), prob.y_star, prob.hypergrad)
+    return (structure, net.content_key(), _spec_key(spec), metrics_fn,
+            recorder, _pallas_key(), _operand_key(operands))
+
+
+def _build_reference_program(prob, W, spec, metrics_fn, recorder
+                             ) -> _ReferenceProgram:
+    """The program for the miss's key.  It closes over that call's
+    mixing op `W`: the trace reads its structure and, for the flight
+    recorder, the bytes per send of the channels `dagm_init_carry`
+    registered in its ledger — all fixed by the key.  Later calls
+    charge their own op's ledger."""
+    from repro.core.dagm import dagm_run_chunk
+    from repro import obs
+    bare = prob.with_data(None)
+
+    # hp, masks and the data are jit *arguments*.  The program is
+    # schedule-agnostic, and — because the serve tier scans the very
+    # same traced operands — batched traced-hp runs are bit-exact with
+    # this solo program.  Closed over, the data would be compiled into
+    # the program as constants (1.4 GB for a 64-agent quadratic with
+    # d2 = 1024), slow to compile and too big for the persistent
+    # compile cache, and the cache would keep it alive.
+    def run(carry, hp, masks, data):
+        return dagm_run_chunk(bare.with_data(data), W, spec, carry,
+                              spec.K, metrics_fn, hp=hp, masks=masks,
+                              recorder=recorder)
+
+    return _ReferenceProgram(
+        obs.TraceCounter("solve_reference_run").wrap(run), bare)
+
+
 def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
                           metrics_fn, recorder=None) -> SolveResult:
-    from repro.core.dagm import (RoundHP, dagm_init_carry,
-                                 dagm_run_chunk)
+    from repro.core.dagm import RoundHP, dagm_init_carry
     from repro.core.mixing import make_mixing_op
     from repro import obs
     tr = obs.tracer()
@@ -145,13 +265,12 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
         with tr.span("init_carry", cat="solver", track="solver"):
             carry0 = dagm_init_carry(prob, W, spec, x0, y0, seed,
                                      recorder=recorder)
-        hp = _schedule_hp(spec)
+        hp = RoundHP(*(jnp.asarray(a, jnp.float32)
+                       for a in _schedule_hp(spec)))
 
         # faults lower once (host-side) to a per-round mask operand;
-        # like hp, the masks enter the program as traced arrays, so
-        # resolving a different FaultSpec against a held compiled
-        # runner costs zero retraces (the bare solve() closure is
-        # still per-call).
+        # like hp, the masks enter the program as traced arrays, so a
+        # different FaultSpec reuses the cached program
         trace = None
         masks = None
         if spec.faults is not None:
@@ -161,32 +280,21 @@ def _solve_dagm_reference(prob, net, spec: SolverSpec, *, x0, y0, seed,
                 masks = jnp.asarray(trace.table_masks(W.sparse),
                                     jnp.float32)
 
-        # hp enters as a jit *argument*: the program is
-        # schedule-agnostic, and — because the serve tier scans the
-        # very same traced operands — batched traced-hp runs are
-        # bit-exact with this solo program.  (The closure itself is
-        # per-call: solo solve() does not cache compiles across
-        # invocations; sweeps belong on tier="serve".)  The problem
-        # data is an argument too: closed over, it would be compiled
-        # into the program as constants (1.4 GB for a 64-agent
-        # quadratic with d2 = 1024), which is slow to compile and too
-        # big for the persistent compile cache.
-        @jax.jit
-        def run(carry, hp, masks, data):
-            return dagm_run_chunk(prob.with_data(data), W, spec, carry,
-                                  spec.K, metrics_fn, hp=hp, masks=masks,
-                                  recorder=recorder)
+        operands = (carry0, hp, masks, prob.data)
+        program, outcome = _REFERENCE_PROGRAMS.get(
+            _reference_key(prob, net, spec, metrics_fn, recorder,
+                           operands),
+            lambda: _build_reference_program(prob, W, spec,
+                                             metrics_fn, recorder))
 
         # trace_compile: from the call until tracing, compiling (or
-        # loading from the persistent cache) and dispatch returned;
-        # chunk: the device execution after it (a sync the result read
-        # below would force anyway — values are unchanged)
+        # loading from the persistent cache) and dispatch returned —
+        # on a cache hit, dispatch alone; chunk: the device execution
+        # after it (a sync the result read below would force anyway —
+        # values are unchanged)
         with tr.span("trace_compile", cat="solver.compile",
-                     track="solver", rounds=spec.K):
-            out = run(
-                carry0, RoundHP(*(jnp.asarray(a, jnp.float32)
-                                  for a in hp)),
-                masks, prob.data)
+                     track="solver", rounds=spec.K, cache=outcome):
+            out = program.run(*operands)
         with tr.span("chunk", cat="solver.chunk", track="solver",
                      rounds=spec.K):
             if tr.enabled:
@@ -319,7 +427,6 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
                    metrics_fn, mesh, g_fn, f_fn, batch,
                    recorder=None) -> SolveResult:
     from repro.distributed.dagm_sharded import (ShardedRoundCoeffs,
-                                                make_sharded_dagm,
                                                 open_sharded_channels,
                                                 sharded_comm_ledger,
                                                 sharded_round_coeffs)
@@ -349,9 +456,6 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
                 "per-agent `batch` pytree (leading agent axis)")
         batch = prob.data
 
-    step, w = make_sharded_dagm(g_fn, f_fn, spec, mesh,
-                                schedule_hp=True, jit_step=False,
-                                recorder=recorder)
     ax = spec.sharded.axis
     ax_names = ax if isinstance(ax, tuple) else (ax,)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -374,6 +478,14 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
         if spec.comm.persist_ef else None
     from repro import obs
     rec = obs.recorder_init(recorder) if recorder is not None else None
+    base = jax.random.PRNGKey(seed ^ 0x5eed) \
+        if pol.stochastic and channels is None else None
+    # the coefficients and γ are (K,) f32 whatever the call: K and the
+    # recorder are in the key
+    (run, w), outcome = _SHARDED_PROGRAMS.get(
+        (g_fn, f_fn, mesh, _spec_key(spec), recorder, _pallas_key(),
+         _operand_key((x0, y0, channels, rec, batch, base))),
+        lambda: _build_sharded_run(g_fn, f_fn, spec, mesh, recorder))
     # the round's scan inputs: the coefficients (float64 host math,
     # rounded to f32 once), γ for the flight rows, and the round index
     # that the stochastic policies fold into their key
@@ -383,12 +495,45 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
                                   sched.beta.astype(np.float64),
                                   spec.curvature, w.w_self)))
     gamma = sched.gamma if rec is not None else None
-    base = jax.random.PRNGKey(seed ^ 0x5eed) \
-        if pol.stochastic and channels is None else None
+    tr = obs.tracer()
+    with tr.span("solve", cat="solver", track="solver", method="dagm",
+                 tier="sharded", K=spec.K, seed=seed,
+                 compile_stages=True):
+        with tr.span("trace_compile", cat="solver.compile",
+                     track="solver", rounds=spec.K, cache=outcome):
+            out = run(x0, y0, channels, rec, batch, base, hp, gamma)
+        with tr.span("chunk", cat="solver.chunk", track="solver",
+                     rounds=spec.K):
+            if tr.enabled:
+                jax.block_until_ready(out)
+        (x, y, channels, rec), ms = out
+        metrics = jax.tree.map(np.asarray, ms)
+    local = jax.tree.map(lambda a: a[0], (x0, y0))
+    ledger = sharded_comm_ledger(spec, local[0], local[1],
+                                 rounds=spec.K)
+    extras = {"ring": w}
+    if rec is not None:
+        extras["flight"] = obs.recorder_rows(rec)
+    return SolveResult(x=x, y=y, metrics=metrics, ledger=ledger,
+                       channels=channels, method="dagm", tier="sharded",
+                       extras=extras)
 
-    # all K rounds in one program: the step scanned on the device, the
-    # metrics stacked and read once.  The batch is an argument — closed
-    # over, it would be compiled in as constants.
+
+_SHARDED_PROGRAMS = _ProgramCache("sharded")
+
+
+def _build_sharded_run(g_fn, f_fn, spec, mesh, recorder):
+    """(jitted run, RingWeights): all K rounds in one program, the
+    step scanned on the device and the metrics stacked.  The batch is
+    an argument — closed over, it would be compiled in as constants.
+    One `TraceCounter("sharded_dagm_run")` wrapper per program, so
+    `jit_traces_total{name="sharded_dagm_run"}` counts real traces."""
+    from repro.distributed.dagm_sharded import make_sharded_dagm
+    from repro import obs
+    step, w = make_sharded_dagm(g_fn, f_fn, spec, mesh,
+                                schedule_hp=True, jit_step=False,
+                                recorder=recorder)
+
     def run(x, y, channels, rec, batch, base, hp, gamma):
         def body(carry, xs):
             x, y, cs, rec = carry
@@ -411,29 +556,7 @@ def _solve_sharded(prob, net, spec: SolverSpec, *, x0, y0, seed,
         ks = jnp.arange(spec.K, dtype=jnp.int32)
         return jax.lax.scan(body, (x, y, channels, rec), (ks, hp, gamma))
 
-    run = obs.TraceCounter("sharded_dagm_run").wrap(run)
-    tr = obs.tracer()
-    with tr.span("solve", cat="solver", track="solver", method="dagm",
-                 tier="sharded", K=spec.K, seed=seed,
-                 compile_stages=True):
-        with tr.span("trace_compile", cat="solver.compile",
-                     track="solver", rounds=spec.K):
-            out = run(x0, y0, channels, rec, batch, base, hp, gamma)
-        with tr.span("chunk", cat="solver.chunk", track="solver",
-                     rounds=spec.K):
-            if tr.enabled:
-                jax.block_until_ready(out)
-        (x, y, channels, rec), ms = out
-        metrics = jax.tree.map(np.asarray, ms)
-    local = jax.tree.map(lambda a: a[0], (x0, y0))
-    ledger = sharded_comm_ledger(spec, local[0], local[1],
-                                 rounds=spec.K)
-    extras = {"ring": w}
-    if rec is not None:
-        extras["flight"] = obs.recorder_rows(rec)
-    return SolveResult(x=x, y=y, metrics=metrics, ledger=ledger,
-                       channels=channels, method="dagm", tier="sharded",
-                       extras=extras)
+    return obs.TraceCounter("sharded_dagm_run").wrap(run), w
 
 
 def _sharded_policy(spec: SolverSpec):

@@ -114,6 +114,7 @@ path end-to-end with no call-site branching.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import warnings
 from typing import Sequence
 
@@ -162,6 +163,14 @@ class Network:
     @property
     def num_edges(self) -> int:
         return int(self.adj.sum()) // 2
+
+    def content_key(self) -> tuple:
+        """Hashable identity by content: name, n and a digest of W.
+        Two Networks with equal (name, n) but different W get different
+        keys, so a program traced for one never runs on the other."""
+        digest = hashlib.sha1(
+            np.ascontiguousarray(self.W).tobytes()).hexdigest()
+        return ("net", self.name, self.n, digest)
 
 
 def make_network(kind: str, n: int, *, weights: str = "metropolis",

@@ -548,8 +548,23 @@ def _count_traces():
 def test_solve_span_counts_compile_stages_bitwise_no_retrace(tier):
     prob, net = _problem()
     spec = _spec(K=8, tier=tier)
-    base = solve(prob, net, spec)          # warms every helper program
     n = _count_traces()
+    if tier == "reference":
+        # the first call of a key traces, lowers and compiles (or
+        # loads) its program: a miss of solve()'s program cache
+        with obs.tracing() as tr:
+            base = solve(prob, net, spec)
+        (sp,) = [e for e in tr.events() if e.name == "solve"]
+        (tc,) = [e for e in tr.events() if e.name == "trace_compile"]
+        assert tc.args["cache"] == "miss" and n[0] > 0
+        assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
+            and sp.args["backend_s"] > 0
+        assert obs.counter_value("jax_compile_seconds_total",
+                                 stage="trace") >= sp.args["trace_s"]
+        tr.clear()
+    else:
+        base = solve(prob, net, spec)      # warms every helper program
+    n[0] = 0
     solve(prob, net, spec)
     off = n[0]
     n[0] = 0
@@ -561,12 +576,12 @@ def test_solve_span_counts_compile_stages_bitwise_no_retrace(tier):
     (sp,) = [e for e in tr.events() if e.name == "solve"]
     assert sp.args["tier"] == tier
     if tier == "reference":
-        # the bare solve() builds a fresh program per call: every call
-        # traces, lowers and compiles (or loads) it
-        assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
-            and sp.args["backend_s"] > 0
-        assert obs.counter_value("jax_compile_seconds_total",
-                                 stage="trace") >= sp.args["trace_s"]
+        # a repeat call of the key runs the cached program: nothing is
+        # traced, lowered or loaded
+        (tc,) = [e for e in tr.events() if e.name == "trace_compile"]
+        assert tc.args["cache"] == "hit" and off == 0
+        assert sp.args["trace_s"] == sp.args["lower_s"] \
+            == sp.args["backend_s"] == 0
     names = {e.name for e in tr.events()}
     if tier == "serve":
         assert {"chunk", "chunk_wait", "boundary"} <= names
@@ -603,7 +618,8 @@ def test_reference_spans_nest_on_the_solver_track():
 def test_sharded_solve_round_spans_bitwise():
     """The sharded tier scans its rounds on the device: one
     trace_compile then one chunk span inside solve, each with
-    rounds=K, and no per-round host span (one-device mesh)."""
+    rounds=K, and no per-round host span (one-device mesh).  The first
+    call of a key compiles; a repeat runs the cached program."""
     import jax
     from jax.sharding import Mesh
     from repro.core import quadratic_bilevel
@@ -612,7 +628,16 @@ def test_sharded_solve_round_spans_bitwise():
     prob = quadratic_bilevel(1, 3, 4, seed=0)
     spec = sharded_spec(alpha=0.05, beta=0.1, M=2, U=2, curvature=5.0,
                         K=3)
-    base = solve(prob, None, spec, mesh=mesh)
+    with obs.tracing() as tr:
+        base = solve(prob, None, spec, mesh=mesh)
+    # the first call of a key builds the step and traces, lowers and
+    # compiles (or loads) the scanned program
+    (sp,) = [e for e in tr.events() if e.name == "solve"]
+    (tc,) = [e for e in tr.events() if e.name == "trace_compile"]
+    assert tc.args["cache"] == "miss"
+    assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
+        and sp.args["backend_s"] > 0
+    tr.clear()
     with obs.tracing() as tr:
         res = solve(prob, None, spec, mesh=mesh)
     assert np.array_equal(np.asarray(base.x), np.asarray(res.x))
@@ -628,9 +653,11 @@ def test_sharded_solve_round_spans_bitwise():
     for e in (tc, ch):
         assert sp.ts_us <= e.ts_us
         assert e.ts_us + e.dur_us <= sp.ts_us + sp.dur_us + 1e-6
-    # make_sharded_dagm builds a fresh step per call
-    assert sp.args["trace_s"] > 0 and sp.args["lower_s"] > 0 \
-        and sp.args["backend_s"] > 0
+    # a repeat call of the key runs the cached program: nothing is
+    # traced, lowered or loaded
+    assert tc.args["cache"] == "hit"
+    assert sp.args["trace_s"] == sp.args["lower_s"] \
+        == sp.args["backend_s"] == 0
     obs.validate_trace(obs.trace_events(tr))
 
 

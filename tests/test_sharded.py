@@ -449,16 +449,29 @@ mesh = Mesh(np.array(jax.devices()).reshape(n), ("data",))
 prob = quadratic_bilevel(n, 3, 4, seed=0)
 curv = float(max(np.linalg.eigvalsh(np.asarray(prob.data["A"][i])).max()
                  for i in range(n)))
+
+
+def same_result(a, b):
+    return (np.array_equal(np.asarray(a.x), np.asarray(b.x))
+            and np.array_equal(np.asarray(a.y), np.asarray(b.y))
+            and set(a.metrics) == set(b.metrics)
+            and all(np.array_equal(a.metrics[k], b.metrics[k])
+                    for k in a.metrics))
+
+
 for name, persist in (("persist", True), ("stoch", False)):
     spec = sharded_spec(alpha=inverse_sqrt_schedule(0.05), beta=0.1, M=4,
                         U=3, curvature=curv, comm="int8+ef",
                         persist_ef=persist, K=K)
+    # the first call of a key traces its program; a repeat runs the
+    # program solve() kept, bitwise the same
     t0 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
     res = solve(prob, None, spec, mesh=mesh, seed=seed)
     t1 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
-    solve(prob, None, spec, mesh=mesh, seed=seed)
+    again = solve(prob, None, spec, mesh=mesh, seed=seed)
     t2 = obs.counter_value("jit_traces_total", name="sharded_dagm_run")
-    print("RUN_TRACES_" + name, int(t1 - t0 == 1 and t2 - t1 == 1))
+    print("RUN_TRACES_" + name, int(t1 - t0 == 1 and t2 - t1 == 0
+                                    and same_result(res, again)))
     # the per-round step driven by hand, with the same keys, channels
     # and coefficients
     step, w = make_sharded_dagm(lambda x, y, b: prob.g(x, y, b),
@@ -498,16 +511,83 @@ for name, persist in (("persist", True), ("stoch", False)):
         np.array_equal(np.asarray(rres.x), np.asarray(res.x))
         and np.array_equal(np.asarray(rres.y), np.asarray(res.y))
         and rres.extras["flight"].shape[0] == K))
+
+# --- solve()'s program cache on the sharded tier ---
+import dataclasses
+from repro.solve.api import _SHARDED_PROGRAMS
+
+traces = [0]
+
+
+def on_compile_event(event, secs, **_):
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        traces[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(on_compile_event)
+
+
+def runs():
+    return obs.counter_value("jit_traces_total", name="sharded_dagm_run")
+
+
+def cache(outcome):
+    return obs.counter_value("solve_program_cache_total", tier="sharded",
+                             outcome=outcome)
+
+
+def cold(spec, seed):
+    # the call with solve()'s program cache emptied first
+    _SHARDED_PROGRAMS.clear()
+    t = runs()
+    res = solve(prob, None, spec, mesh=mesh, seed=seed)
+    assert runs() == t + 1
+    return res
+
+
+def ledger_rows(ledger):
+    return dict((k, (ch.sends, ch.bytes, ch.floats))
+                for k, ch in ledger.channels.items())
+
+
+spec = sharded_spec(alpha=0.05, beta=0.1, M=4, U=3, curvature=curv, K=K)
+m0 = cache("miss")
+solve(prob, None, spec, mesh=mesh, seed=1)
+traces[0], t0, h0 = 0, runs(), cache("hit")
+hit = solve(prob, None, spec, mesh=mesh, seed=2)
+print("CACHE_REPEAT_NO_TRACE", int(traces[0] == 0 and runs() == t0
+                                   and cache("hit") == h0 + 1
+                                   and cache("miss") == m0 + 1))
+ref = cold(spec, 2)
+print("CACHE_REPEAT_BITWISE", int(
+    traces[0] > 0 and same_result(hit, ref)
+    and ledger_rows(hit.ledger) == ledger_rows(ref.ledger)
+    and hit.ledger.total_bytes == ref.ledger.total_bytes > 0))
+for name, other in (
+        ("curvature", dataclasses.replace(spec, curvature=1.5 * curv)),
+        ("K", dataclasses.replace(spec, K=K - 4))):
+    solve(prob, None, spec, mesh=mesh, seed=1)
+    m0, t0 = cache("miss"), runs()
+    res = solve(prob, None, other, mesh=mesh, seed=1)
+    print("CACHE_OWN_PROGRAM_" + name, int(
+        cache("miss") == m0 + 1 and runs() == t0 + 1
+        and same_result(res, cold(other, 1))))
+swept = sharded_spec(alpha=inverse_sqrt_schedule(0.05), beta=0.1, M=4, U=3,
+                     curvature=curv, K=K)
+first = solve(prob, None, spec, mesh=mesh, seed=1)
+t0, h0 = runs(), cache("hit")
+second = solve(prob, None, swept, mesh=mesh, seed=1)
+print("CACHE_SCHEDULE_SHARED", int(
+    runs() == t0 and cache("hit") == h0 + 1
+    and not np.array_equal(np.asarray(first.x), np.asarray(second.x))
+    and same_result(second, cold(swept, 1))
+    and same_result(first, cold(spec, 1))))
 """
 
 
-def test_solve_sharded_compressed_scan_matches_step_loop(tmp_path):
-    """`solve(tier="sharded")` scans the per-round step on the device:
-    a persist_ef int8+ef solve and a stochastic int8+ef solve (decaying
-    alpha) equal the hand-driven per-round step loop with the same
-    channels and keys (`fold_in(PRNGKey(seed ^ 0x5eed), k)`) bitwise,
-    with and without the flight recorder, and each solve() traces its
-    run program exactly once (`sharded_dagm_run`)."""
+@pytest.fixture(scope="module")
+def solve_comm_vals():
+    """The values SCRIPT_SOLVE_COMM prints, from one run of it."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     script = SCRIPT_SOLVE_COMM.format(src=os.path.abspath(src))
     out = subprocess.run([sys.executable, "-c", script],
@@ -518,10 +598,45 @@ def test_solve_sharded_compressed_scan_matches_step_loop(tmp_path):
         parts = line.split()
         if len(parts) == 2:
             vals[parts[0]] = float(parts[1])
+    return vals
+
+
+def test_solve_sharded_compressed_scan_matches_step_loop(solve_comm_vals):
+    """`solve(tier="sharded")` scans the per-round step on the device:
+    a persist_ef int8+ef solve and a stochastic int8+ef solve (decaying
+    alpha) equal the hand-driven per-round step loop with the same
+    channels and keys (`fold_in(PRNGKey(seed ^ 0x5eed), k)`) bitwise,
+    with and without the flight recorder.  The first solve() of a key
+    traces its run program (`sharded_dagm_run`) once, and a repeat
+    traces nothing and returns the same result."""
+    vals = solve_comm_vals
     for name in ("persist", "stoch"):
         assert vals[f"RUN_TRACES_{name}"] == 1
         assert vals[f"SOLVE_BITEXACT_{name}"] == 1
         assert vals[f"RECORDED_BITSAME_{name}"] == 1
+
+
+def test_repeat_sharded_solve_traces_nothing_bitwise(solve_comm_vals):
+    """A repeat call of a key traces nothing (no jaxpr trace at all,
+    `sharded_dagm_run` unchanged, one hit and one miss counted) and
+    returns x, y, metrics and a ledger equal to a cold call's."""
+    assert solve_comm_vals["CACHE_REPEAT_NO_TRACE"] == 1
+    assert solve_comm_vals["CACHE_REPEAT_BITWISE"] == 1
+
+
+@pytest.mark.parametrize("change", ["curvature", "K"])
+def test_sharded_trace_constants_get_their_own_programs(solve_comm_vals,
+                                                        change):
+    """The curvature (a constant of the DIHGP coefficients' program) and
+    K (the scan length) key the program: each is a miss that traces,
+    and equals a cold call."""
+    assert solve_comm_vals["CACHE_OWN_PROGRAM_" + change] == 1
+
+
+def test_sharded_schedule_sweep_shares_one_program(solve_comm_vals):
+    """Two specs that differ only in the α schedule share one trace, and
+    each is bitwise equal to a cold solve() with its own schedule."""
+    assert solve_comm_vals["CACHE_SCHEDULE_SHARED"] == 1
 
 
 SCRIPT_FLIGHT = r"""

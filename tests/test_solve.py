@@ -405,3 +405,172 @@ def test_shared_engine_cache_hits_across_serve_solves(ring_setup):
     assert eng.stats.traces == traces      # cache hit, no retrace
     assert eng.stats.cache_hits > 0
     assert eng.metrics_fn is before        # side effect undone
+
+
+# ---------------------------------------------------------------------------
+# the compiled-program cache (reference tier)
+# ---------------------------------------------------------------------------
+
+_TRACES = [0]
+
+
+def _on_compile_event(event, secs, **_):
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        _TRACES[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def _run_traces():
+    from repro import obs
+    return obs.counter_value("jit_traces_total", name="solve_reference_run")
+
+
+def _cache_count(outcome, tier="reference"):
+    from repro import obs
+    return obs.counter_value("solve_program_cache_total", tier=tier,
+                             outcome=outcome)
+
+
+def _cold(prob, net, spec, **kw):
+    """The same call with solve()'s program cache emptied first: it
+    traces, lowers and compiles its program anew."""
+    from repro.solve.api import _REFERENCE_PROGRAMS
+    _REFERENCE_PROGRAMS.clear()
+    t0 = _run_traces()
+    res = solve(prob, net, spec, **kw)
+    assert _run_traces() == t0 + 1
+    return res
+
+
+def _assert_same_result(a, b):
+    assert np.array_equal(np.asarray(a.x), np.asarray(b.x))
+    assert np.array_equal(np.asarray(a.y), np.asarray(b.y))
+    assert set(a.metrics) == set(b.metrics)
+    for k in a.metrics:
+        assert np.array_equal(np.asarray(a.metrics[k]),
+                              np.asarray(b.metrics[k]))
+
+
+def _ledger_rows(ledger):
+    return {name: (ch.sends, ch.bytes, ch.floats)
+            for name, ch in ledger.channels.items()}
+
+
+@pytest.mark.parametrize("comm", ["identity", "int8+ef"])
+def test_repeat_reference_solve_traces_nothing_bitwise(comm):
+    """A repeat call of a key traces nothing (no jaxpr trace at all,
+    `solve_reference_run` unchanged) and returns x, y, metrics and a
+    ledger equal to a cold call's."""
+    net = make_network("ring", 8)
+    prob = quadratic_bilevel(8, 3, 6, seed=1, mu_f=0.4)
+    spec = dagm_spec(alpha=0.05, beta=0.1, K=6, M=3, U=2, comm=comm)
+    solve(prob, net, spec, seed=1)                    # miss: compiles
+    _TRACES[0], t0 = 0, _run_traces()
+    hit = solve(prob, net, spec, seed=2)
+    assert _TRACES[0] == 0 and _run_traces() == t0
+    cold = _cold(prob, net, spec, seed=2)
+    assert _TRACES[0] > 0
+    _assert_same_result(hit, cold)
+    assert _ledger_rows(hit.ledger) == _ledger_rows(cold.ledger)
+    assert hit.ledger.total_bytes == cold.ledger.total_bytes > 0
+    assert hit.ledger is not cold.ledger
+
+
+@pytest.mark.parametrize("change", ["network", "curvature", "K"])
+def test_trace_constants_get_their_own_programs(change):
+    """What the trace bakes in keys the program: two Networks with
+    equal (name, n) but different W, two curvatures (a constant of
+    the matrix-free DIHGP) and two K (the scan length) each miss, and
+    the second run equals a cold call of its own."""
+    net0 = make_network("erdos_renyi", 8, r=0.4, seed=0)
+    net1 = dataclasses.replace(
+        make_network("erdos_renyi", 8, r=0.4, seed=3), name=net0.name)
+    assert not np.array_equal(net0.W, net1.W)
+    prob = quadratic_bilevel(8, 3, 4, seed=0)
+    spec0 = dagm_spec(alpha=0.05, beta=0.1, K=6, M=3, U=2,
+                      mixing="dense", dihgp="matrix_free", curvature=8.0)
+    net, spec = {
+        "network": (net1, spec0),
+        "curvature": (net0, dataclasses.replace(spec0, curvature=9.0)),
+        "K": (net0, dataclasses.replace(spec0, K=4)),
+    }[change]
+    solve(prob, net0, spec0, seed=1)
+    misses, t0 = _cache_count("miss"), _run_traces()
+    res = solve(prob, net, spec, seed=1)
+    assert _cache_count("miss") == misses + 1
+    assert _run_traces() == t0 + 1
+    _assert_same_result(res, _cold(prob, net, spec, seed=1))
+    if change == "network":
+        # not the first network's program: the trajectory differs
+        first = solve(prob, net0, spec0, seed=1)
+        assert not np.array_equal(np.asarray(first.x), np.asarray(res.x))
+
+
+@pytest.mark.parametrize("change", ["alpha", "faults"])
+def test_schedule_sweeps_share_one_program(change):
+    """The α schedule and the fault schedule are operands: two specs
+    that differ only there share one trace, and each call is bitwise
+    equal to a cold solve() with its own schedule."""
+    from repro.faults import FaultSpec
+    net = make_network("ring", 8)
+    prob = quadratic_bilevel(8, 3, 6, seed=2, mu_f=0.4)
+    spec0 = dagm_spec(alpha=0.05, beta=0.1, K=6, M=3, U=2,
+                      mixing="sparse_gather")
+    if change == "alpha":
+        specs = [spec0, dataclasses.replace(spec0, schedule=ScheduleSpec(
+            alpha=inverse_sqrt_schedule(0.05), beta=0.1))]
+    else:
+        specs = [dataclasses.replace(spec0, faults=FaultSpec(
+            drop_prob=0.3, seed=s)) for s in (1, 2)]
+    first = solve(prob, net, specs[0], seed=1)
+    t0, hits = _run_traces(), _cache_count("hit")
+    second = solve(prob, net, specs[1], seed=1)
+    assert _run_traces() == t0 and _cache_count("hit") == hits + 1
+    assert not np.array_equal(np.asarray(first.x), np.asarray(second.x))
+    _assert_same_result(second, _cold(prob, net, specs[1], seed=1))
+    _assert_same_result(first, _cold(prob, net, specs[0], seed=1))
+
+
+def test_program_cache_holds_no_problem_data(ring_setup):
+    """The cached program closes over the problem with data=None, so
+    the cache never keeps a dataset alive; the data is an operand."""
+    from repro.solve.api import _REFERENCE_PROGRAMS
+    net, prob = ring_setup
+    solve(prob, net, dagm_spec(alpha=0.05, beta=0.1, K=4, M=2, U=1))
+    entries = _REFERENCE_PROGRAMS.entries()
+    assert entries and all(e.problem.data is None for e in entries)
+    ours = [e for e in entries if e.problem.f is prob.f]
+    assert len(ours) == 1 and ours[0].problem.g is prob.g
+
+
+def test_solve_program_cache_total_counts_hits_and_misses():
+    net = make_network("ring", 6)
+    prob = quadratic_bilevel(6, 2, 3, seed=4)
+    spec = dagm_spec(alpha=0.05, beta=0.1, K=3, M=2, U=1)
+    hits, misses = _cache_count("hit"), _cache_count("miss")
+    for seed in range(3):
+        solve(prob, net, spec, seed=seed)
+    assert _cache_count("miss") == misses + 1
+    assert _cache_count("hit") == hits + 2
+
+
+def test_program_cache_is_a_bounded_lru():
+    from repro import obs
+    from repro.solve.api import _ProgramCache
+    cache = _ProgramCache("lru-test", size=2)
+    built = []
+
+    def build(k):
+        return lambda: built.append(k) or k
+
+    assert cache.get("a", build("a")) == ("a", "miss")
+    assert cache.get("b", build("b")) == ("b", "miss")
+    assert cache.get("a", build("a")) == ("a", "hit")   # a is now newest
+    assert cache.get("c", build("c")) == ("c", "miss")  # evicts b
+    assert cache.entries() == ["a", "c"]
+    assert cache.get("b", build("b")) == ("b", "miss")
+    assert built == ["a", "b", "c", "b"]
+    assert obs.counter_value("solve_program_cache_total", tier="lru-test",
+                             outcome="hit") == 1.0
